@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -273,32 +272,25 @@ def main_flash_bwd(json_path: str | None = None) -> None:
 
 def main_flash_ring(json_path: str | None = None, ring_devices: int = 8
                     ) -> None:
-    """Ring shoot-out: sequence-parallel flash_ring on an emulated
-    ring-devices-wide mesh vs the single-device Pallas kernel, with the
+    """Ring shoot-out: sequence-parallel flash_ring on a ring of up to
+    ring_devices devices vs the single-device Pallas kernel, with the
     per-hop-count parity residual recorded.
 
-    Needs >= ring_devices devices; off-TPU with a single CPU device it
-    re-execs itself in a child with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count`` set, so
-    ``python -m benchmarks.bench_kernels`` works from any host.  Records
-    BENCH_flash_ring.json: tokens/s for both paths (interpret mode off
-    TPU — a correctness checkpoint, not a speed claim) and the max
-    |ring - single-device| output residual per ring width (1/2/4/8
+    Runs in this process on the devices it has — the chips on a TPU
+    host, or the CPU backend's emulated devices under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; with fewer
+    than two devices it fails rather than starting another process,
+    which could not reach a chip this one holds.  Records
+    BENCH_flash_ring.json: tokens/s for both paths (interpret mode on the
+    CPU backend — a correctness checkpoint, not a speed claim) and the
+    max |ring - single-device| output residual per ring width (1/2/4/8
     hops), i.e. the merge's split-point invariance at kernel scale.
     """
-    if len(jax.devices()) < ring_devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            f" --xla_force_host_platform_device_count="
-                            f"{ring_devices}").strip()
-        # force cpu: the device-count flag only affects the host platform,
-        # so inheriting e.g. JAX_PLATFORMS=tpu would re-exec forever
-        env["JAX_PLATFORMS"] = "cpu"
-        subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_kernels",
-             "--ring-only", json_path or "BENCH_flash_ring.json"],
-            check=True, env=env)
-        return
+    if len(jax.devices()) < 2:
+        raise SystemExit(
+            f"flash_ring bench needs >= 2 devices, found "
+            f"{len(jax.devices())} — on the CPU backend set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={ring_devices}")
 
     from repro.kernels.ring_attention import ring_flash_attention
     from repro.launch.mesh import auto_mesh
@@ -327,7 +319,7 @@ def main_flash_ring(json_path: str | None = None, ring_devices: int = 8
     emit("kernels/flash_ring_single_us", t_single,
          f"backend={jax.default_backend()}")
     hops = 2
-    while hops <= results["n_devices"]:
+    while hops <= min(results["n_devices"], ring_devices):
         mesh = auto_mesh((hops,), ("model",))
         ring = lambda q_, k_, v_: ring_flash_attention(
             q_, k_, v_, q_pos=q_pos, kv_valid=valid, mesh=mesh)
@@ -805,7 +797,7 @@ if __name__ == "__main__":
     main_flash("BENCH_flash.json")
     main_flash_int("BENCH_flash_int.json")
     main_flash_bwd("BENCH_flash_bwd.json")
-    main_flash_ring("BENCH_flash_ring.json")
     main_decode("BENCH_decode.json")
     main_serve("BENCH_serve.json")
     main_block("BENCH_block.json")
+    main_flash_ring("BENCH_flash_ring.json")   # needs >= 2 devices

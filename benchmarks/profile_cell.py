@@ -24,7 +24,7 @@ from repro.launch.mesh import make_production_mesh  # noqa: E402
 def profile(arch: str, shape: str, multi_pod: bool = False, top: int = 12):
     mesh = make_production_mesh(multi_pod=multi_pod)
     cell = build_cell(arch, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = cell.lower().compile()
     txt = compiled.as_text()
     p = H.HloProgram(txt)

@@ -17,6 +17,8 @@ def main() -> None:
                     help="comma-separated subset: table1,table2,fig4,"
                          "kernels,flash,roofline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_kernels, fig4_combined_savings, roofline,
                    table1_accuracy, table2_dualmode_overhead)
     sections = {

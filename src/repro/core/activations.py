@@ -56,9 +56,11 @@ def relu2(x):
 
 
 def _ste(fwd_quant: Callable, surrogate: Callable) -> Callable:
-    """Straight-through wrapper: forward bits, backward surrogate grad."""
+    """Straight-through wrapper: forward bits, backward surrogate grad.
+    The unit computes in f32 words; the result keeps the input's dtype."""
     def f(x):
-        return surrogate(x) + jax.lax.stop_gradient(fwd_quant(x) - surrogate(x))
+        q = fwd_quant(x).astype(x.dtype)
+        return surrogate(x) + jax.lax.stop_gradient(q - surrogate(x))
     return f
 
 

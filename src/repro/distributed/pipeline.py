@@ -23,22 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: top-level (>=0.6, check_vma) vs
-    jax.experimental.shard_map (older, check_rep).  Shared by the pipeline
-    here and the ring-attention kernel (kernels/ring_attention.py) — the
-    one place the version fork lives."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
-_shard_map = shard_map_compat
-
-
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
     return (n_stages - 1) / (n_stages + n_micro - 1)
 
@@ -95,6 +79,7 @@ def pipelined(stage_fn: Callable, mesh: Mesh, n_micro: int,
 
     def wrapped(stage_params, x):
         in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-        return _shard_map(run, mesh, in_specs, P())(stage_params, x)
+        return jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(stage_params, x)
 
     return wrapped

@@ -81,7 +81,7 @@ interpret backends (where interpret-mode Pallas loses to XLA).
 
 Resolution is also mesh-aware when the caller opts in with a
 ``ring_axis``: when 'auto' lands on a blocked impl (float OR int) AND
-the ambient ``with mesh:`` context shards the KV sequence over that
+the ambient ``jax.set_mesh`` context shards the KV sequence over that
 axis (both sequence dims divisible), the pick upgrades to 'flash_ring'
 — the sequence-parallel ring composition of the same kernel, which
 folds float (m, l, acc) or snapped int (m, S, acc) hop partials
@@ -185,7 +185,7 @@ _MESH_OVERRIDE: list = []
 
 
 def ambient_mesh():
-    """The active ``with mesh:`` context's Mesh, or None.
+    """The active ``with jax.set_mesh(mesh):`` context's Mesh, or None.
 
     The ring-attention provider and the 'auto' ring upgrade read the
     mesh from here, so model code threads only the ``ring_axis`` string
@@ -193,12 +193,8 @@ def ambient_mesh():
     time inside jit."""
     if _MESH_OVERRIDE:
         return _MESH_OVERRIDE[-1]
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):     # pragma: no cover
-        return None
-    return None if mesh is None or mesh.empty else mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def ring_axis_size(ring_axis: str | None) -> int:
@@ -324,7 +320,7 @@ def resolve_attention(impl: str, s_q: int, t_kv: int,
 
     Mesh-aware (opt-in): with a non-empty ``ring_axis``, an 'auto' pick
     of a blocked path — float OR int — upgrades to 'flash_ring' when the
-    ambient ``with mesh:`` context carries that axis with size > 1 and
+    ambient ``jax.set_mesh`` context carries that axis with size > 1 and
     both sequence dims divide it — the shapes where the KV sequence
     actually shards.  Configs opt in via ``ModelConfig.ring_axis``; the
     default (``""``) never changes today's resolution.

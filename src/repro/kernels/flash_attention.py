@@ -20,7 +20,7 @@ like the naive path, so all three implementations agree on masking.
 
 Non-divisible S/T are padded up to the block grid (``kernels/tiling.py``
 policy) and the output sliced back; padded KV rows are simply invalid.
-Runs on CPU with ``interpret=True`` (the default off-TPU).
+Runs on CPU with ``interpret=True`` (the default on the CPU backend).
 
 Residual contract: the forward emits the per-row online-softmax
 statistics ``(m, l)`` — running max and normalizer of the PRE-SCALED
@@ -47,24 +47,56 @@ _STATE_LANES = 128   # lane width of the (m, l) scratch rows
 
 def attention_blockspecs(bq: int, bkv: int, g: int, hd: int, hv: int):
     """(in_specs for (q_pos, kv_valid, q, k, v), out_spec) shared by every
-    flash kernel flavor.  Index maps take (b, head, q_tile, *rest) with
-    the kv tile as the LAST grid dim, so the same specs serve the float
-    kernel's 4D grid and the int kernel's 5D (extra sweep dim) grid.
+    flash kernel flavor, over the head-major kernel layout of
+    :func:`kernel_operands`.  Index maps take (b, head, q_tile, *rest)
+    with the kv tile as the LAST grid dim, so the same specs serve the
+    float kernel's 4D grid and the int kernel's 5D (extra sweep dim) grid.
+
+    Every block's last two dims are (sublane-aligned rows, full minor
+    dim) — the TPU block rule — which the token-major (B, T, K, h) layout
+    cannot give a one-head block.
     """
     in_specs = [
-        pl.BlockSpec((1, bq), lambda b_, h_, qi, *r: (b_, qi)),
-        pl.BlockSpec((1, bkv), lambda b_, h_, qi, *r: (b_, r[-1])),
-        pl.BlockSpec((1, bq, 1, 1, hd),
-                     lambda b_, h_, qi, *r: (b_, qi, h_ // g, h_ % g, 0)),
-        pl.BlockSpec((1, bkv, 1, hd),
-                     lambda b_, h_, qi, *r: (b_, r[-1], h_ // g, 0)),
-        pl.BlockSpec((1, bkv, 1, hv),
-                     lambda b_, h_, qi, *r: (b_, r[-1], h_ // g, 0)),
+        pl.BlockSpec((1, bq, 1), lambda b_, h_, qi, *r: (b_, qi, 0)),
+        pl.BlockSpec((1, 1, bkv), lambda b_, h_, qi, *r: (b_, 0, r[-1])),
+        pl.BlockSpec((1, 1, bq, hd), lambda b_, h_, qi, *r: (b_, h_, qi, 0)),
+        pl.BlockSpec((1, 1, bkv, hd),
+                     lambda b_, h_, qi, *r: (b_, h_ // g, r[-1], 0)),
+        pl.BlockSpec((1, 1, bkv, hv),
+                     lambda b_, h_, qi, *r: (b_, h_ // g, r[-1], 0)),
     ]
-    out_spec = pl.BlockSpec(
-        (1, bq, 1, 1, hv),
-        lambda b_, h_, qi, *r: (b_, qi, h_ // g, h_ % g, 0))
+    out_spec = pl.BlockSpec((1, 1, bq, hv),
+                            lambda b_, h_, qi, *r: (b_, h_, qi, 0))
     return in_specs, out_spec
+
+
+def heads_major(x):
+    """(B, S, K, G, d) query-layout array -> (B, K*G, S, d) kernel layout."""
+    b, s, kh, g, d = x.shape
+    return x.transpose(0, 2, 3, 1, 4).reshape(b, kh * g, s, d)
+
+
+def tokens_major(x, kh: int):
+    """Inverse of :func:`heads_major`: (B, K*G, S, d) -> (B, S, K, G, d)."""
+    b, h, s, d = x.shape
+    return x.reshape(b, kh, h // kh, s, d).transpose(0, 3, 1, 2, 4)
+
+
+def kernel_operands(q, q_pos, k, v, kv_valid, bq: int, bkv: int):
+    """Pad the five blocked-attention operands to the (bq, bkv) grid and
+    lay them out for the kernels: q (B, K*G, S, h), k (B, K, T, h),
+    v (B, K, T, hv), q_pos (B, S, 1) and kv_valid (B, 1, T) int32.
+
+    One definition for every blocked flash flavor: q/q_pos pad along the
+    query axis, k/v/kv_valid along the kv axis (validity pads with 0, so
+    padded keys are invalid)."""
+    qf, _ = tiling.pad_dim(q, 1, bq)
+    qp, _ = tiling.pad_dim(q_pos.astype(jnp.int32), 1, bq)
+    kf, _ = tiling.pad_dim(k, 1, bkv)
+    vf, _ = tiling.pad_dim(v, 1, bkv)
+    valid, _ = tiling.pad_dim(kv_valid.astype(jnp.int32), 1, bkv, value=0)
+    return (heads_major(qf), qp[:, :, None], kf.transpose(0, 2, 1, 3),
+            vf.transpose(0, 2, 1, 3), valid[:, None, :])
 
 
 def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
@@ -79,28 +111,28 @@ def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
     """
     bq, bkv = tiling.attention_blocks(s_q, t_kv)
     return {"flash_fwd": {
-        "in:q_pos": ((1, bq), jnp.int32),
-        "in:kv_valid": ((1, bkv), jnp.int32),
-        "in:q": ((1, bq, 1, 1, hd), jnp.float32),
-        "in:k": ((1, bkv, 1, hd), jnp.float32),
-        "in:v": ((1, bkv, 1, hv), jnp.float32),
-        "out:o": ((1, bq, 1, 1, hv), jnp.float32),
-        "out:m": ((1, 1, 1, bq), jnp.float32),
-        "out:l": ((1, 1, 1, bq), jnp.float32),
+        "in:q_pos": ((1, bq, 1), jnp.int32),
+        "in:kv_valid": ((1, 1, bkv), jnp.int32),
+        "in:q": ((1, 1, bq, hd), jnp.float32),
+        "in:k": ((1, 1, bkv, hd), jnp.float32),
+        "in:v": ((1, 1, bkv, hv), jnp.float32),
+        "out:o": ((1, 1, bq, hv), jnp.float32),
+        "out:m": ((1, 1, bq, 1), jnp.float32),
+        "out:l": ((1, 1, bq, 1), jnp.float32),
         "scratch:m": ((bq, _STATE_LANES), jnp.float32),
         "scratch:l": ((bq, _STATE_LANES), jnp.float32),
         "scratch:acc": ((bq, tiling.scratch_lanes(hv)), jnp.float32),
     }}
 
 
-def rowstat_blockspec(bq: int, g: int):
-    """BlockSpec for the (B, K, G, S) per-row statistic arrays (m, l, D)
-    on the forward/dq grid layout (b, head, q_tile, *rest)."""
-    return pl.BlockSpec((1, 1, 1, bq),
-                        lambda b_, h_, qi, *r: (b_, h_ // g, h_ % g, qi))
+def rowstat_blockspec(bq: int, lanes: int = 1):
+    """BlockSpec for the (B, K*G, S, lanes) per-row statistic arrays on
+    the forward/dq grid layout (b, head, q_tile, *rest)."""
+    return pl.BlockSpec((1, 1, bq, lanes),
+                        lambda b_, h_, qi, *r: (b_, h_, qi, 0))
 
 
-def masked_score_block(q, kb, qpos_ref, valid_ref, kv_tile: int, *,
+def masked_score_block(q, kb, q_pos, valid, kv_tile: int, *,
                        block_kv: int, causal: bool, t_kv: int):
     """(masked scores, mask) tile — ONE definition of the flash masking.
 
@@ -108,7 +140,9 @@ def masked_score_block(q, kb, qpos_ref, valid_ref, kv_tile: int, *,
     masked keys (matching the naive path bitwise) and ``-inf`` for
     tiling-padded phantom keys, which must carry NO mass.  Shared by the
     forward body and both backward kernels so forward and backward can
-    never disagree on which keys are "off".  The mask is returned because
+    never disagree on which keys are "off".  ``q_pos`` is the (bq, 1)
+    column of query positions (or one scalar position) and ``valid`` the
+    (1, bkv) int validity row of the tile.  The mask is returned because
     the backward must zero dS where the score was replaced by the
     constant MASK_VALUE — the ``jnp.where`` routes no gradient into the
     untaken branch, and the reference VJP therefore sends exactly 0
@@ -117,11 +151,10 @@ def masked_score_block(q, kb, qpos_ref, valid_ref, kv_tile: int, *,
     """
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (bq, bkv)
-    mask = valid_ref[...] != 0                            # (1, bkv) -> bcast
+    mask = valid != 0                                     # (1, bkv) -> bcast
     kv_pos = kv_tile * block_kv + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     if causal:
-        q_pos = qpos_ref[...].reshape(-1, 1)              # (bq, 1)
         mask = mask & (kv_pos <= q_pos)
     s = jnp.where(mask, s, dp.MASK_VALUE)
     return jnp.where(kv_pos < t_kv, s, -jnp.inf), \
@@ -143,10 +176,10 @@ def _flash_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, 0, :].astype(jnp.float32)          # (bq, h) pre-scaled
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, h)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, hv)
-    s, _ = masked_score_block(q, kb, qpos_ref, valid_ref, kj,
+    q = q_ref[0, 0].astype(jnp.float32)                   # (bq, h) pre-scaled
+    kb = k_ref[0, 0].astype(jnp.float32)                  # (bkv, h)
+    vb = v_ref[0, 0].astype(jnp.float32)                  # (bkv, hv)
+    s, _ = masked_score_block(q, kb, qpos_ref[0], valid_ref[0], kj,
                               block_kv=block_kv, causal=causal, t_kv=t_kv)
 
     m, l = m_ref[:, :1], l_ref[:, :1]                     # (bq, 1)
@@ -161,10 +194,10 @@ def _flash_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     @pl.when(kj == pl.num_programs(3) - 1)
     def _():
         out = dp.online_softmax_finish(l_ref[:, :1], acc_ref[:, :hv])
-        o_ref[0, :, 0, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
         if with_stats:
-            m_out_ref[0, 0, 0, :] = m_ref[:, 0]
-            l_out_ref[0, 0, 0, :] = l_ref[:, 0]
+            m_out_ref[0, 0] = m_ref[:, :1]
+            l_out_ref[0, 0] = l_ref[:, :1]
 
 
 def _flash_fwd_call(q, k, v, q_pos, kv_valid, *, causal: bool, bq: int,
@@ -180,14 +213,14 @@ def _flash_fwd_call(q, k, v, q_pos, kv_valid, *, causal: bool, bq: int,
     b, s_q, kh, g, hd = q.shape
     t = k.shape[1]
     hv = v.shape[-1]
-    qf, qp, kf, vf, valid = tiling.pad_attention_operands(
-        q, q_pos, k, v, kv_valid, bq, bkv)
-    s_p, t_p = qf.shape[1], kf.shape[1]
+    qf, qp, kf, vf, valid = kernel_operands(q, q_pos, k, v, kv_valid,
+                                            bq, bkv)
+    s_p, t_p = qf.shape[2], kf.shape[2]
 
     in_specs, out_spec = attention_blockspecs(bq, bkv, g, hd, hv)
-    stat_spec = rowstat_blockspec(bq, g)
-    o_shape = jax.ShapeDtypeStruct((b, s_p, kh, g, hv), v.dtype)
-    stat_shape = jax.ShapeDtypeStruct((b, kh, g, s_p), jnp.float32)
+    stat_spec = rowstat_blockspec(bq)
+    o_shape = jax.ShapeDtypeStruct((b, kh * g, s_p, hv), v.dtype)
+    stat_shape = jax.ShapeDtypeStruct((b, kh * g, s_p, 1), jnp.float32)
     grid = (b, kh * g, s_p // bq, t_p // bkv)
     out = pl.pallas_call(
         functools.partial(_flash_body, block_kv=bkv, causal=causal,
@@ -207,10 +240,11 @@ def _flash_fwd_call(q, k, v, q_pos, kv_valid, *, causal: bool, bq: int,
         interpret=interpret,
     )(qp, valid, qf, kf, vf)
     if not with_stats:
-        return tiling.unpad(out, 1, s_q)
+        return tiling.unpad(tokens_major(out, kh), 1, s_q)
     o, m, l = out
-    return (tiling.unpad(o, 1, s_q), tiling.unpad(m, 3, s_q),
-            tiling.unpad(l, 3, s_q))
+    return (tiling.unpad(tokens_major(o, kh), 1, s_q),
+            tiling.unpad(m.reshape(b, kh, g, s_p), 3, s_q),
+            tiling.unpad(l.reshape(b, kh, g, s_p), 3, s_q))
 
 
 def flash_attention_pallas(q, k, v, *, q_pos, kv_valid, causal: bool = True,
@@ -237,7 +271,7 @@ def flash_attention_pallas(q, k, v, *, q_pos, kv_valid, causal: bool = True,
     """
     hd = q.shape[-1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = tiling.interpret_mode()
     scale = (1.0 / hd ** 0.5) if scale is None else scale
     bq, bkv = tiling.attention_blocks(q.shape[1], k.shape[1])
     bq = bq if block_q is None else block_q

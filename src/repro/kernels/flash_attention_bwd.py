@@ -60,8 +60,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import datapath as dp
 from . import tiling
-from .flash_attention import (attention_blockspecs, masked_score_block,
-                              rowstat_blockspec)
+from .flash_attention import (attention_blockspecs, heads_major,
+                              kernel_operands, masked_score_block,
+                              rowstat_blockspec, tokens_major)
 
 
 def _probs_from_stats(m_row, l_row, s):
@@ -74,8 +75,8 @@ def _probs_from_stats(m_row, l_row, s):
 def _rowsum_do_o(do_ref, o_ref):
     """Dao's per-row scalar D = rowsum(dO ∘ O) from the two output-layout
     tiles — the fused replacement for the old host-side pre-pass."""
-    do = do_ref[0, :, 0, 0, :].astype(jnp.float32)        # (bq, hv)
-    o = o_ref[0, :, 0, 0, :].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)                 # (bq, hv)
+    o = o_ref[0, 0].astype(jnp.float32)
     return jnp.sum(do * o, axis=-1, keepdims=True)        # (bq, 1)
 
 
@@ -94,15 +95,15 @@ def _tile_grads(qpos_ref, valid_ref, q_ref, k_ref, v_ref, do_ref, m_ref,
 
     Returns (p, ds, q, kb, do) — everything either kernel body combines.
     """
-    q = q_ref[0, :, 0, 0, :].astype(jnp.float32)          # (bq, h) pre-scaled
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, h)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, hv)
-    do = do_ref[0, :, 0, 0, :].astype(jnp.float32)        # (bq, hv)
-    s, mask = masked_score_block(q, kb, qpos_ref, valid_ref, kv_tile,
+    q = q_ref[0, 0].astype(jnp.float32)                   # (bq, h) pre-scaled
+    kb = k_ref[0, 0].astype(jnp.float32)                  # (bkv, h)
+    vb = v_ref[0, 0].astype(jnp.float32)                  # (bkv, hv)
+    do = do_ref[0, 0].astype(jnp.float32)                 # (bq, hv)
+    s, mask = masked_score_block(q, kb, qpos_ref[0], valid_ref[0], kv_tile,
                                  block_kv=block_kv, causal=causal,
                                  t_kv=t_kv)
-    m_row = m_ref[0, 0, 0, :].reshape(-1, 1)              # (bq, 1)
-    l_row = l_ref[0, 0, 0, :].reshape(-1, 1)
+    m_row = m_ref[0, 0]                                   # (bq, 1)
+    l_row = l_ref[0, 0]
     p = _probs_from_stats(m_row, l_row, s)                # (bq, bkv)
     dpv = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -132,7 +133,7 @@ def _dq_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
 
     @pl.when(kj == pl.num_programs(3) - 1)
     def _():
-        dq_ref[0, :, 0, 0, :] = dq_acc[:, :hd]
+        dq_ref[0, 0] = dq_acc[:, :hd]
 
 
 def _dkdv_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
@@ -163,8 +164,8 @@ def _dkdv_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
     @pl.when((g_ == pl.num_programs(3) - 1)
              & (qi == pl.num_programs(4) - 1))
     def _():
-        dk_ref[0, :, 0, :] = dk_acc[:, :hd]
-        dv_ref[0, :, 0, :] = dv_acc[:, :hv]
+        dk_ref[0, 0] = dk_acc[:, :hd]
+        dv_ref[0, 0] = dv_acc[:, :hv]
 
 
 def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
@@ -172,27 +173,27 @@ def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
     ``flash_attention.vmem_plan`` for the contract)."""
     bq, bkv = tiling.attention_blocks(s_q, t_kv)
     common = {
-        "in:q_pos": ((1, bq), jnp.int32),
-        "in:kv_valid": ((1, bkv), jnp.int32),
-        "in:q": ((1, bq, 1, 1, hd), jnp.float32),
-        "in:k": ((1, bkv, 1, hd), jnp.float32),
-        "in:v": ((1, bkv, 1, hv), jnp.float32),
-        "in:o": ((1, bq, 1, 1, hv), jnp.float32),
-        "in:do": ((1, bq, 1, 1, hv), jnp.float32),
-        "in:m": ((1, 1, 1, bq), jnp.float32),
-        "in:l": ((1, 1, 1, bq), jnp.float32),
+        "in:q_pos": ((1, bq, 1), jnp.int32),
+        "in:kv_valid": ((1, 1, bkv), jnp.int32),
+        "in:q": ((1, 1, bq, hd), jnp.float32),
+        "in:k": ((1, 1, bkv, hd), jnp.float32),
+        "in:v": ((1, 1, bkv, hv), jnp.float32),
+        "in:o": ((1, 1, bq, hv), jnp.float32),
+        "in:do": ((1, 1, bq, hv), jnp.float32),
+        "in:m": ((1, 1, bq, 1), jnp.float32),
+        "in:l": ((1, 1, bq, 1), jnp.float32),
     }
     return {
         "flash_bwd_dq": dict(
             common,
-            **{"out:dq": ((1, bq, 1, 1, hd), jnp.float32),
+            **{"out:dq": ((1, 1, bq, hd), jnp.float32),
                "scratch:dq_acc": ((bq, tiling.scratch_lanes(hd)),
                                   jnp.float32),
                "scratch:d": ((bq, tiling.scratch_lanes(1)), jnp.float32)}),
         "flash_bwd_dkdv": dict(
             common,
-            **{"out:dk": ((1, bkv, 1, hd), jnp.float32),
-               "out:dv": ((1, bkv, 1, hv), jnp.float32),
+            **{"out:dk": ((1, 1, bkv, hd), jnp.float32),
+               "out:dv": ((1, 1, bkv, hv), jnp.float32),
                "scratch:dk_acc": ((bkv, tiling.scratch_lanes(hd)),
                                   jnp.float32),
                "scratch:dv_acc": ((bkv, tiling.scratch_lanes(hv)),
@@ -217,28 +218,27 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, q_pos, kv_valid,
     # Dao et al.'s D = rowsum(dO∘O) is fused INTO the kernels (dq: first
     # KV step into scratch; dk/dv: per q-tile visit) — only o/do are
     # padded here, no per-row D array ever exists in HBM
-    qf, qp, kf, vf, valid = tiling.pad_attention_operands(
-        q, q_pos, k, v, kv_valid, bq, bkv)
-    of, _ = tiling.pad_dim(o.astype(jnp.float32), 1, bq)
-    dof, _ = tiling.pad_dim(do.astype(jnp.float32), 1, bq)
+    qf, qp, kf, vf, valid = kernel_operands(q, q_pos, k, v, kv_valid,
+                                            bq, bkv)
+    of = heads_major(tiling.pad_dim(o.astype(jnp.float32), 1, bq)[0])
+    dof = heads_major(tiling.pad_dim(do.astype(jnp.float32), 1, bq)[0])
     # phantom q rows: o/dO pad with 0 (so the fused D is 0 there) and l
     # with 1, so the re-derived probabilities stay finite and every
     # phantom contribution is 0
-    mf, _ = tiling.pad_dim(m, 3, bq)
-    lf, _ = tiling.pad_dim(l, 3, bq, value=1.0)
-    s_p, t_p = qf.shape[1], kf.shape[1]
+    s_p, t_p = qf.shape[2], kf.shape[2]
+    mf = tiling.pad_dim(m, 3, bq)[0].reshape(b, kh * g, s_p, 1)
+    lf = tiling.pad_dim(l, 3, bq, value=1.0)[0].reshape(b, kh * g, s_p, 1)
 
     body = dict(block_kv=bkv, causal=causal, t_kv=t)
     in_specs, out_spec = attention_blockspecs(bq, bkv, g, hd, hv)
-    stat = rowstat_blockspec(bq, g)
+    stat = rowstat_blockspec(bq)
     dq = pl.pallas_call(
         functools.partial(_dq_body, **body),
         grid=(b, kh * g, s_p // bq, t_p // bkv),
         in_specs=in_specs + [out_spec, out_spec, stat, stat],  # + o, do, m, l
-        out_specs=pl.BlockSpec(
-            (1, bq, 1, 1, hd),
-            lambda b_, h_, qi, kj: (b_, qi, h_ // g, h_ % g, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s_p, kh, g, hd), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b_, h_, qi, kj: (b_, h_, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, kh * g, s_p, hd), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bq, tiling.scratch_lanes(hd)), jnp.float32),
             pltpu.VMEM((bq, tiling.scratch_lanes(1)), jnp.float32)],  # D
@@ -248,38 +248,36 @@ def flash_attention_bwd_pallas(q, k, v, o, m, l, do, *, q_pos, kv_valid,
     # dk/dv grid: kv tiles OUTER, (group, q tile) inner — consecutive
     # inner steps revisit the same output block, so the accumulation
     # (incl. the GQA sum over groups) stays in VMEM scratch
+    def q_rows(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda b_, kh_, kv_, g_, qi: (b_, kh_ * g + g_, qi, 0))
+
+    def kv_rows(width):
+        return pl.BlockSpec((1, 1, bkv, width),
+                            lambda b_, kh_, kv_, g_, qi: (b_, kh_, kv_, 0))
+
     dkdv_specs = [
-        pl.BlockSpec((1, bq), lambda b_, kh_, kv_, g_, qi: (b_, qi)),
-        pl.BlockSpec((1, bkv), lambda b_, kh_, kv_, g_, qi: (b_, kv_)),
-        pl.BlockSpec((1, bq, 1, 1, hd),
-                     lambda b_, kh_, kv_, g_, qi: (b_, qi, kh_, g_, 0)),
-        pl.BlockSpec((1, bkv, 1, hd),
-                     lambda b_, kh_, kv_, g_, qi: (b_, kv_, kh_, 0)),
-        pl.BlockSpec((1, bkv, 1, hv),
-                     lambda b_, kh_, kv_, g_, qi: (b_, kv_, kh_, 0)),
-        pl.BlockSpec((1, bq, 1, 1, hv),                    # o
-                     lambda b_, kh_, kv_, g_, qi: (b_, qi, kh_, g_, 0)),
-        pl.BlockSpec((1, bq, 1, 1, hv),                    # do
-                     lambda b_, kh_, kv_, g_, qi: (b_, qi, kh_, g_, 0)),
-    ] + [pl.BlockSpec((1, 1, 1, bq),
-                      lambda b_, kh_, kv_, g_, qi: (b_, kh_, g_, qi))] * 2
+        pl.BlockSpec((1, bq, 1), lambda b_, kh_, kv_, g_, qi: (b_, qi, 0)),
+        pl.BlockSpec((1, 1, bkv),
+                     lambda b_, kh_, kv_, g_, qi: (b_, 0, kv_)),
+        q_rows(hd), kv_rows(hd), kv_rows(hv),
+        q_rows(hv), q_rows(hv),                            # o, do
+        q_rows(1), q_rows(1),                              # m, l
+    ]
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_body, **body),
         grid=(b, kh, t_p // bkv, g, s_p // bq),
         in_specs=dkdv_specs,
-        out_specs=[
-            pl.BlockSpec((1, bkv, 1, hd),
-                         lambda b_, kh_, kv_, g_, qi: (b_, kv_, kh_, 0)),
-            pl.BlockSpec((1, bkv, 1, hv),
-                         lambda b_, kh_, kv_, g_, qi: (b_, kv_, kh_, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((b, t_p, kh, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((b, t_p, kh, hv), jnp.float32)],
+        out_specs=[kv_rows(hd), kv_rows(hv)],
+        out_shape=[jax.ShapeDtypeStruct((b, kh, t_p, hd), jnp.float32),
+                   jax.ShapeDtypeStruct((b, kh, t_p, hv), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bkv, tiling.scratch_lanes(hd)), jnp.float32),
             pltpu.VMEM((bkv, tiling.scratch_lanes(hv)), jnp.float32)],
         interpret=interpret,
     )(qp, valid, qf, kf, vf, of, dof, mf, lf)
 
-    return (tiling.unpad(dq, 1, s_q), tiling.unpad(dk, 1, t),
-            tiling.unpad(dv, 1, t))
+    return (tiling.unpad(tokens_major(dq, kh), 1, s_q),
+            tiling.unpad(dk.transpose(0, 2, 1, 3), 1, t),
+            tiling.unpad(dv.transpose(0, 2, 1, 3), 1, t))

@@ -62,11 +62,12 @@ from repro.core.fixedpoint import EXP_FRAC, I32, T_FRAC, dequantize, quantize
 
 from . import datapath as dp
 from . import dispatch, tiling
-from .flash_attention import _STATE_LANES, attention_blockspecs, \
-    rowstat_blockspec
+from .flash_attention import (_STATE_LANES, attention_blockspecs,
+                              kernel_operands, rowstat_blockspec,
+                              tokens_major)
 
 
-def int_score_words(q, kb, qpos_ref, valid_ref, kv_tile, *, block_kv: int,
+def int_score_words(q, kb, q_pos, valid, kv_tile, *, block_kv: int,
                     causal: bool, t_kv: int):
     """One tile of S5.10 score WORDS — the int twin of
     ``flash_attention.masked_score_block``, shared by every int kernel
@@ -76,11 +77,10 @@ def int_score_words(q, kb, qpos_ref, valid_ref, kv_tile, *, block_kv: int,
     tiling-phantom positions with the ``PHANTOM_Q`` sentinel."""
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    mask = valid_ref[...] != 0                            # (1, bkv) -> bcast
+    mask = valid != 0                                     # (1, bkv) -> bcast
     kv_pos = kv_tile * block_kv + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     if causal:
-        q_pos = qpos_ref[...].reshape(-1, 1)
         mask = mask & (kv_pos <= q_pos)
     s = jnp.where(mask, s, dp.MASK_VALUE)
     sq = quantize(s)                                      # S5.10 score words
@@ -153,11 +153,11 @@ def _flash_snap_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         s_ref[...] = jnp.zeros_like(s_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, 0, :].astype(jnp.float32)          # (bq, h) pre-scaled
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, h)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, hv)
-    sq = int_score_words(q, kb, qpos_ref, valid_ref, kj, block_kv=block_kv,
-                         causal=causal, t_kv=t_kv)
+    q = q_ref[0, 0].astype(jnp.float32)                   # (bq, h) pre-scaled
+    kb = k_ref[0, 0].astype(jnp.float32)                  # (bkv, h)
+    vb = v_ref[0, 0].astype(jnp.float32)                  # (bkv, hv)
+    sq = int_score_words(q, kb, qpos_ref[0], valid_ref[0], kj,
+                         block_kv=block_kv, causal=causal, t_kv=t_kv)
 
     m_new, S_new, acc_new = snap_tile_update(
         m_ref[:, :1], s_ref[:, :nb], acc_ref[:, :hv], sq, vb, guard_shift)
@@ -170,13 +170,13 @@ def _flash_snap_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         if with_partial:
             # UNNORMALIZED partial out: the ring folds (m, S, acc) across
             # hops with the int monoid and finishes ONCE at the end
-            o_ref[0, :, 0, 0, :] = acc_ref[:, :hv]
-            m_out_ref[0, 0, 0, :] = m_ref[:, 0]
-            s_out_ref[0, 0, 0, :, :] = s_ref[:, :nb]
+            o_ref[0, 0] = acc_ref[:, :hv]
+            m_out_ref[0, 0] = m_ref[:, :1]
+            s_out_ref[0, 0] = s_ref[:, :nb]
         else:
             l = unit.online_finish_int(s_ref[:, :nb])     # (bq,)
             out = acc_ref[:, :hv] / l[:, None].astype(jnp.float32)
-            o_ref[0, :, 0, 0, :] = out.astype(o_ref.dtype)
+            o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -193,28 +193,23 @@ def _flash_snap_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
     # naive op order: q*scale in f32 BEFORE the dot (pins the score words)
     q = q.astype(jnp.float32) * scale
 
-    qf, qp, kf, vf, valid = tiling.pad_attention_operands(
-        q, q_pos, k, v, kv_valid, bq, bkv)
-    s_p, t_p = qf.shape[1], kf.shape[1]
+    qf, qp, kf, vf, valid = kernel_operands(q, q_pos, k, v, kv_valid,
+                                            bq, bkv)
+    s_p, t_p = qf.shape[2], kf.shape[2]
 
     in_specs, out_spec = attention_blockspecs(bq, bkv, g, hd, hv)
     grid = (b, kh * g, s_p // bq, t_p // bkv)
     if with_partial:
-        out_specs = [
-            pl.BlockSpec((1, bq, 1, 1, hv),
-                         lambda b_, h_, qi, kj: (b_, qi, h_ // g, h_ % g, 0)),
-            rowstat_blockspec(bq, g),
-            pl.BlockSpec((1, 1, 1, bq, nb),
-                         lambda b_, h_, qi, kj: (b_, h_ // g, h_ % g, qi, 0)),
-        ]
+        out_specs = [out_spec, rowstat_blockspec(bq),
+                     rowstat_blockspec(bq, nb)]
         out_shape = [
-            jax.ShapeDtypeStruct((b, s_p, kh, g, hv), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, g, s_p), jnp.int32),
-            jax.ShapeDtypeStruct((b, kh, g, s_p, nb), jnp.int32),
+            jax.ShapeDtypeStruct((b, kh * g, s_p, hv), jnp.float32),
+            jax.ShapeDtypeStruct((b, kh * g, s_p, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, kh * g, s_p, nb), jnp.int32),
         ]
     else:
         out_specs = out_spec
-        out_shape = jax.ShapeDtypeStruct((b, s_p, kh, g, hv), v.dtype)
+        out_shape = jax.ShapeDtypeStruct((b, kh * g, s_p, hv), v.dtype)
     out = pl.pallas_call(
         functools.partial(_flash_snap_body, block_kv=bkv, causal=causal,
                           t_kv=t, guard_shift=guard_shift,
@@ -233,9 +228,10 @@ def _flash_snap_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
     )(qp, valid, qf, kf, vf)
     if with_partial:
         acc, m, S = out
-        return (tiling.unpad(acc, 1, s_q), tiling.unpad(m, 3, s_q),
-                tiling.unpad(S, 3, s_q))
-    return tiling.unpad(out, 1, s_q)
+        return (tiling.unpad(tokens_major(acc, kh), 1, s_q),
+                tiling.unpad(m.reshape(b, kh, g, s_p), 3, s_q),
+                tiling.unpad(S.reshape(b, kh, g, s_p, nb), 3, s_q))
+    return tiling.unpad(tokens_major(out, kh), 1, s_q)
 
 
 def flash_attention_pallas_int(q, k, v, *, q_pos, kv_valid,
@@ -262,7 +258,7 @@ def flash_attention_pallas_int(q, k, v, *, q_pos, kv_valid,
     hd = q.shape[-1]
     t = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = tiling.interpret_mode()
     scale = (1.0 / hd ** 0.5) if scale is None else scale
     if guard_shift is None:
         guard_shift = max(0, t.bit_length() - 16)
@@ -292,10 +288,10 @@ def _flash_int_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, 0, :].astype(jnp.float32)          # (bq, h) pre-scaled
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, h)
-    sq = int_score_words(q, kb, qpos_ref, valid_ref, kj, block_kv=block_kv,
-                         causal=causal, t_kv=t_kv)
+    q = q_ref[0, 0].astype(jnp.float32)                   # (bq, h) pre-scaled
+    kb = k_ref[0, 0].astype(jnp.float32)                  # (bkv, h)
+    sq = int_score_words(q, kb, qpos_ref[0], valid_ref[0], kj,
+                         block_kv=block_kv, causal=causal, t_kv=t_kv)
 
     m = m_ref[:, :1]                                      # (bq, 1)
 
@@ -313,7 +309,7 @@ def _flash_int_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref,
     def _():
         p = unit.online_probs_int(m, l_ref[:, :1], sq, guard_shift)
         pf = dequantize(p, EXP_FRAC)                      # exact prob floats
-        vb = v_ref[0, :, 0, :].astype(jnp.float32)        # (bkv, hv)
+        vb = v_ref[0, 0].astype(jnp.float32)              # (bkv, hv)
         # acc scratch is lane-rounded (hv may be off the 128 grid — MLA);
         # only the live [:, :hv] slice carries data
         acc_ref[:, :hv] = acc_ref[:, :hv] + jnp.dot(
@@ -321,7 +317,7 @@ def _flash_int_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref,
 
     @pl.when((phase == 2) & (kj == pl.num_programs(4) - 1))
     def _():
-        o_ref[0, :, 0, 0, :] = acc_ref[:, :hv].astype(o_ref.dtype)
+        o_ref[0, 0] = acc_ref[:, :hv].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -339,17 +335,17 @@ def _flash_int_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
     # identical to the naive einsum, keeping the quantized words pinned
     q = q.astype(jnp.float32) * scale
 
-    qf, qp, kf, vf, valid = tiling.pad_attention_operands(
-        q, q_pos, k, v, kv_valid, bq, bkv)
-    s_p, t_p = qf.shape[1], kf.shape[1]
+    qf, qp, kf, vf, valid = kernel_operands(q, q_pos, k, v, kv_valid,
+                                            bq, bkv)
+    s_p, t_p = qf.shape[2], kf.shape[2]
 
     in_specs, out_spec = attention_blockspecs(bq, bkv, g, hd, hv)
     # only the emit sweep reads v: pin its block index to 0 during the
     # max/sum sweeps (ph // 2 = 0, 0, 1) so v HBM->VMEM traffic stays ~1x
     # instead of riding every kv step of all three sweeps
     in_specs[4] = pl.BlockSpec(
-        (1, bkv, 1, hv),
-        lambda b_, h_, qi, ph, kj: (b_, kj * (ph // 2), h_ // g, 0))
+        (1, 1, bkv, hv),
+        lambda b_, h_, qi, ph, kj: (b_, h_ // g, kj * (ph // 2), 0))
     grid = (b, kh * g, s_p // bq, 3, t_p // bkv)          # 3 = sweeps
     out = pl.pallas_call(
         functools.partial(_flash_int_body, block_kv=bkv, causal=causal,
@@ -357,7 +353,7 @@ def _flash_int_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s_p, kh, g, hv), v.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kh * g, s_p, hv), v.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, _STATE_LANES), jnp.int32),    # running max m
             pltpu.VMEM((bq, _STATE_LANES), jnp.int32),    # guard-shifted l
@@ -366,7 +362,7 @@ def _flash_int_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
         ],
         interpret=interpret,
     )(qp, valid, qf, kf, vf)
-    return tiling.unpad(out, 1, s_q)
+    return tiling.unpad(tokens_major(out, kh), 1, s_q)
 
 
 def flash_attention_pallas_int3(q, k, v, *, q_pos, kv_valid,
@@ -384,7 +380,7 @@ def flash_attention_pallas_int3(q, k, v, *, q_pos, kv_valid,
     hd = q.shape[-1]
     t = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = tiling.interpret_mode()
     scale = (1.0 / hd ** 0.5) if scale is None else scale
     bq, bkv = tiling.attention_blocks(q.shape[1], t)
     bq = bq if block_q is None else block_q
@@ -429,12 +425,12 @@ def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
     bq, bkv = tiling.attention_blocks(s_q, t_kv)
     nb = unit.N_SNAP_BUCKETS
     common = {
-        "in:q_pos": ((1, bq), jnp.int32),
-        "in:kv_valid": ((1, bkv), jnp.int32),
-        "in:q": ((1, bq, 1, 1, hd), jnp.float32),
-        "in:k": ((1, bkv, 1, hd), jnp.float32),
-        "in:v": ((1, bkv, 1, hv), jnp.float32),
-        "out:o": ((1, bq, 1, 1, hv), jnp.float32),
+        "in:q_pos": ((1, bq, 1), jnp.int32),
+        "in:kv_valid": ((1, 1, bkv), jnp.int32),
+        "in:q": ((1, 1, bq, hd), jnp.float32),
+        "in:k": ((1, 1, bkv, hd), jnp.float32),
+        "in:v": ((1, 1, bkv, hv), jnp.float32),
+        "out:o": ((1, 1, bq, hv), jnp.float32),
         "scratch:m": ((bq, _STATE_LANES), jnp.int32),
         "scratch:s": ((bq, _STATE_LANES), jnp.int32),
         "scratch:acc": ((bq, tiling.scratch_lanes(hv)), jnp.float32),
@@ -442,8 +438,8 @@ def vmem_plan(s_q: int, t_kv: int, hd: int, hv: int, g: int = 1):
     return {
         "flash_int_onesweep": dict(
             common,
-            **{"out:part_m": ((1, 1, 1, bq), jnp.int32),
-               "out:part_s": ((1, 1, 1, bq, nb), jnp.int32)}),
+            **{"out:part_m": ((1, 1, bq, 1), jnp.int32),
+               "out:part_s": ((1, 1, bq, nb), jnp.int32)}),
         "flash_int_threesweep": dict(common),
     }
 

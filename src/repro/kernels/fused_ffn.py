@@ -190,7 +190,7 @@ def vmem_plan(m: int, k: int, f: int):
 
 def _ffn_entry(x, wg, wu, mode):
     return fused_glu_pallas(
-        x, wg, wu, mode=mode, interpret=jax.default_backend() != "tpu")
+        x, wg, wu, mode=mode, interpret=tiling.interpret_mode())
 
 
 dispatch.register_ffn("fused_pallas", _ffn_entry)

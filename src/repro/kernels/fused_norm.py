@@ -383,16 +383,12 @@ def vmem_plan(m: int, d: int, f: int):
             "norm_glu_fwd": norm_glu}
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 dispatch.register_norm("fused_pallas", {
     "residual_norm": lambda x, r, g, b, *, kind, eps: fused_residual_norm(
-        x, r, g, b, kind=kind, eps=eps, interpret=_interp()),
+        x, r, g, b, kind=kind, eps=eps, interpret=tiling.interpret_mode()),
     "norm_linear": lambda x, g, b, w, *, kind, eps: fused_norm_linear(
-        x, g, b, w, kind=kind, eps=eps, interpret=_interp()),
+        x, g, b, w, kind=kind, eps=eps, interpret=tiling.interpret_mode()),
     "norm_glu": lambda x, g, b, wg, wu, *, kind, eps, mode: fused_norm_glu(
         x, g, b, wg, wu, kind=kind, eps=eps, mode=mode,
-        interpret=_interp()),
+        interpret=tiling.interpret_mode()),
 })

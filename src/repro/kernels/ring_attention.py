@@ -37,7 +37,8 @@ Shapes match every other flash flavor (GQA/MLA compatible):
 
 with S and T both divisible by the ring axis size.  Runs on CPU under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` with
-``interpret=True`` (the default off-TPU) — the multi-device CI lane.
+``interpret=True`` (the default on the CPU backend) — the multi-device
+CI lane.
 
 DUAL-MODE ring (``softmax_impl='dualmode'``, forward-only): the snapped
 int monoid of :mod:`repro.core.softmax_unit` is a partial contract
@@ -58,7 +59,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import softmax_unit as unit
-from repro.distributed.pipeline import shard_map_compat
 
 from . import datapath as dp
 from . import dispatch, tiling
@@ -270,7 +270,7 @@ def ring_flash_attention(q, k, v, *, q_pos, kv_valid, mesh=None,
     Takes GLOBAL arrays and wraps the per-shard ring loop in a
     ``shard_map`` over ``axis``: q/q_pos/k/v/kv_valid shard along their
     sequence dims, everything else is replicated.  ``mesh=None`` picks
-    up the ambient ``with mesh:`` context.  Differentiable: the custom
+    up the ambient ``jax.set_mesh`` context.  Differentiable: the custom
     VJP composes the dedicated backward kernels per hop (reverse
     rotation, dk/dv accumulated on the shard that owns the KV block).
 
@@ -291,7 +291,7 @@ def ring_flash_attention(q, k, v, *, q_pos, kv_valid, mesh=None,
     if mesh is None or axis not in mesh.axis_names:
         raise ValueError(
             f"ring_flash_attention needs a mesh with axis {axis!r} — pass "
-            "mesh= or run under `with mesh:` (launch/mesh.auto_mesh)")
+            "mesh= or run under `with jax.set_mesh(mesh):` (launch/mesh.auto_mesh)")
     n = mesh.shape[axis]
     s_q, hd = q.shape[1], q.shape[-1]
     t = k.shape[1]
@@ -300,7 +300,7 @@ def ring_flash_attention(q, k, v, *, q_pos, kv_valid, mesh=None,
             f"flash_ring shards the sequence dims over {axis!r} (size "
             f"{n}): s_q={s_q} and t_kv={t} must both divide")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = tiling.interpret_mode()
     scale = (1.0 / hd ** 0.5) if scale is None else scale
     bq, bkv = tiling.attention_blocks(s_q // n, t // n)
     bq = bq if block_q is None else block_q
@@ -338,7 +338,8 @@ def ring_flash_attention(q, k, v, *, q_pos, kv_valid, mesh=None,
     in_specs = (seq(5), seq(4), seq(4), seq(2), seq(2))
     out_specs = ((seq(5), seq(4, 3), seq(4, 3)) if return_stats
                  else seq(5))
-    fn = shard_map_compat(local, mesh, in_specs, out_specs)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(qf, k, v, q_pos.astype(jnp.int32), kv_valid)
 
 

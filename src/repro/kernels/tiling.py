@@ -23,6 +23,10 @@ VMEM_CORE_BUDGET = 16 * 1024 * 1024  # whole-kernel VMEM per TensorCore:
 #   every pallas_call's resident set — double-buffered in/out tiles plus
 #   scratch — must fit this; repro.analysis.vmem audits each kernel's
 #   declared ``vmem_plan()`` against it over the canonical shape grid
+UNIT_LIVE_TILES = 16   # tile-sized values the unit's own kernels
+#   (dualmode_softmax) hold at once: in and out, double-buffered, plus
+#   the int body's temporaries — the v5e compiler asked 25.8 MiB for the
+#   GELU-mode int body on 2 MiB tiles, i.e. ~13 live tiles
 
 
 def cdiv(a: int, b: int) -> int:
@@ -82,18 +86,20 @@ def row_block(n_rows: int, n_cols: int, itemsize: int = 4) -> int:
     """Rows per block for whole-row kernels (row reductions need full rows).
 
     The row length is fixed at ``n_cols`` (pad it to a LANE multiple first);
-    rows per block fill the VMEM tile budget, SUBLANE-aligned.  Callers pad
-    the row count to a multiple of the returned block.
+    rows per block fill a 1/UNIT_LIVE_TILES share of VMEM, SUBLANE-aligned.
+    Callers pad the row count to a multiple of the returned block.
     """
-    rows = max(VMEM_TILE_BUDGET // (max(n_cols, 1) * itemsize), SUBLANE)
+    budget = VMEM_CORE_BUDGET // UNIT_LIVE_TILES
+    rows = max(budget // (max(n_cols, 1) * itemsize), SUBLANE)
     return fit_block(n_rows, SUBLANE, rows)
 
 
 def tile2d(m: int, n: int, itemsize: int = 4) -> tuple[int, int]:
-    """(bm, bn) for elementwise 2D kernels: LANE-wide, budget-bounded."""
+    """(bm, bn) for elementwise 2D kernels: LANE-wide, each tile a
+    1/UNIT_LIVE_TILES share of VMEM."""
+    budget = VMEM_CORE_BUDGET // UNIT_LIVE_TILES
     bn = fit_block(n, LANE, 512)
-    bm = fit_block(m, SUBLANE,
-                   max(VMEM_TILE_BUDGET // (bn * itemsize), SUBLANE))
+    bm = fit_block(m, SUBLANE, max(budget // (bn * itemsize), SUBLANE))
     return bm, bn
 
 
@@ -133,49 +139,47 @@ DECODE_SPLIT_KEYS = 2048     # min keys per split before another split pays
 DECODE_MAX_SPLITS = 8        # partial-merge fan-in cap
 
 
-# TPU generations that expose one TensorCore per chip (no megacore) —
-# the device_kind fallback when the runtime doesn't report ``num_cores``.
-_SINGLE_CORE_TPU_KINDS = ("lite", "v5e", "v6e")
+# TensorCores per chip, keyed by ``device_kind`` as the TPU runtime
+# reports it ("TPU v5 lite" is the v5e).  A kind missing here raises —
+# the split-KV grid is sized from this, and a guess would size it wrong.
+TPU_CORES_PER_CHIP = {"TPU v5 lite": 1}
 
-_CORE_COUNT_CACHE: dict[tuple[str, str], int] = {}
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on the default backend.
+
+    The one home of that decision: the TPU compiles every kernel, the CPU
+    backend interprets them (the test path), and any other backend
+    raises — a program that meant to run on the chip must never fall
+    back to interpreting kernels in silence."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and run interpreted on CPU; "
+        f"the {backend!r} backend is neither")
 
 
 def device_core_count() -> int:
     """Cores on the primary device — the parallelism the split-KV decode
     grid is trying to fill.
 
-    Derived from the actual JAX backend, not the host: on TPU the
-    per-chip TensorCore count (``num_cores`` where the runtime exposes
-    it, else inferred from the device kind — single-core for the
-    inference generations, megacore pair otherwise); on CPU/interpret
-    backends a fixed DECODE_MAX_SPLITS rather than ``os.cpu_count()``,
-    which over-split on TPU hosts (decode splits sized from a 96-way
-    host) and under-split in throttled CI containers.  The lookup is
-    cached per (platform, device_kind) — it runs inside the decode-step
-    build path."""
+    On TPU the per-chip TensorCore count of the device kind
+    (:data:`TPU_CORES_PER_CHIP`); on the CPU backend, where the grid is
+    interpreted, a fixed DECODE_MAX_SPLITS so split counts stay
+    deterministic across hosts.  Any other device raises."""
     import jax
-    try:
-        dev = jax.devices()[0]
-        key = (dev.platform, str(getattr(dev, "device_kind", "")))
-    except Exception:       # pragma: no cover - device probing best-effort
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return DECODE_MAX_SPLITS
-    if key not in _CORE_COUNT_CACHE:
-        _CORE_COUNT_CACHE[key] = _probe_core_count(dev, key)
-    return _CORE_COUNT_CACHE[key]
-
-
-def _probe_core_count(dev, key: tuple[str, str]) -> int:
-    platform, kind = key
-    if platform == "tpu":
-        n = getattr(dev, "num_cores", None)
-        if n:
-            return int(n)
-        kind_l = kind.lower()
-        return 1 if any(s in kind_l for s in _SINGLE_CORE_TPU_KINDS) else 2
-    # CPU / GPU-interpret: the decode grid is emulated; a fixed cap keeps
-    # split counts deterministic across hosts instead of tracking
-    # whatever cpu_count the CI container happens to advertise.
-    return DECODE_MAX_SPLITS
+    if dev.platform == "tpu" and dev.device_kind in TPU_CORES_PER_CHIP:
+        return TPU_CORES_PER_CHIP[dev.device_kind]
+    raise RuntimeError(
+        f"unknown device {dev.platform}/{dev.device_kind!r}: add its core "
+        "count to tiling.TPU_CORES_PER_CHIP")
 
 
 def decode_splits(t_kv: int, max_splits: int | None = None) -> int:
@@ -190,10 +194,23 @@ def decode_splits(t_kv: int, max_splits: int | None = None) -> int:
     return int(max(1, min(max_splits, t_kv // DECODE_SPLIT_KEYS)))
 
 
-def decode_kv_block(t_kv: int, num_splits: int) -> int:
-    """KV tile width for one decode split: LANE-aligned, <= 512 keys, and
-    dividing the minimally padded per-split extent."""
-    return fit_block(cdiv(t_kv, max(num_splits, 1)), LANE, 512)
+def vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) tile once laid out on the (sublane,
+    lane) grid: rows pad to the dtype's packed sublane count (8 for
+    32-bit, 16 for 16-bit), cols to the lane width."""
+    return (round_up(rows, SUBLANE * max(4 // itemsize, 1))
+            * round_up(cols, LANE) * itemsize)
+
+
+def decode_kv_block(t_kv: int, num_splits: int, key_bytes: int = 0) -> int:
+    """KV tile width for one decode split: LANE-aligned, <= 512 keys,
+    dividing the minimally padded per-split extent, and — given
+    ``key_bytes``, the VMEM one key of the block takes across all its
+    heads — small enough that one K or V tile stays in the tile budget."""
+    cap = 512
+    if key_bytes:
+        cap = min(cap, max(LANE, VMEM_TILE_BUDGET // key_bytes))
+    return fit_block(cdiv(t_kv, max(num_splits, 1)), LANE, cap)
 
 
 #   paged-KV policy: the serve engine's block pool carves the cache into
@@ -224,18 +241,3 @@ def attention_blocks(s_q: int, t_kv: int) -> tuple[int, int]:
     with kv LANE-aligned (it is the score tile's minor dim).
     """
     return fit_block(s_q, SUBLANE, 128), fit_block(t_kv, LANE, 512)
-
-
-def pad_attention_operands(q, q_pos, k, v, kv_valid, bq: int, bkv: int):
-    """Pad the five blocked-attention operands up to the (bq, bkv) grid.
-
-    One definition for every flash kernel flavor: q/q_pos pad along the
-    query axis, k/v/kv_valid along the kv axis (validity pads with 0 so
-    padded keys are invalid).  Returns the padded operands.
-    """
-    qf, _ = pad_dim(q, 1, bq)
-    qp, _ = pad_dim(q_pos.astype(jnp.int32), 1, bq)
-    kf, _ = pad_dim(k, 1, bkv)
-    vf, _ = pad_dim(v, 1, bkv)
-    valid, _ = pad_dim(kv_valid.astype(jnp.int32), 1, bkv, value=0)
-    return qf, qp, kf, vf, valid

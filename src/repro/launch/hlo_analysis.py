@@ -319,18 +319,31 @@ def analyze_hlo(hlo_text: str) -> dict[str, float]:
 
 # ---------------- roofline ----------------
 
-# hardware constants (TPU v5e per chip; assignment-given)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by jax's ``device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (= 200e9 bytes/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 1600e9 / 8},
+}
 
 
-def roofline_terms(flops: float, bytes_accessed: float, wire_bytes: float
-                   ) -> dict[str, Any]:
-    """Three per-chip time lower bounds, seconds."""
-    t_c = flops / PEAK_FLOPS
-    t_m = bytes_accessed / HBM_BW
-    t_n = wire_bytes / ICI_BW
+def device_peaks(device_kind: str) -> dict[str, float]:
+    """Peak FLOP/s and bytes/s of one chip of ``device_kind``; a kind
+    missing from :data:`PEAKS` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; have {sorted(PEAKS)}") from None
+
+
+def roofline_terms(flops: float, bytes_accessed: float, wire_bytes: float,
+                   device_kind: str) -> dict[str, Any]:
+    """Three per-chip time lower bounds on a ``device_kind`` chip, s."""
+    peak = device_peaks(device_kind)
+    t_c = flops / peak["flops"]
+    t_m = bytes_accessed / peak["hbm_bw"]
+    t_n = wire_bytes / peak["ici_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
               key=lambda kv: kv[1])
     return {"t_compute": t_c, "t_memory": t_m, "t_collective": t_n,

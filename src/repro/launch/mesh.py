@@ -6,14 +6,10 @@ import jax
 
 
 def auto_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis_types where this jax has the
-    concept (jax >= 0.5); on older jax Auto is the only behavior, so the
-    kwarg is simply omitted.  Every mesh in the repo goes through here."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-propagated shardings).
+    Every mesh in the repo goes through here."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -11,6 +11,7 @@ import time
 import jax
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_lm
 from repro.serve import Request, ServeEngine
 
@@ -79,6 +80,7 @@ def main() -> None:
                          "expired requests retire with reason 'deadline'")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (registry.reduced_config(args.arch) if args.reduced
            else registry.get_config(args.arch))

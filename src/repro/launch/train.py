@@ -5,9 +5,6 @@
 
 --reduced runs the smoke-scale config (CPU-feasible); full-scale runs use
 the production mesh on real hardware (same code path the dry-run proves).
-On a TPU fleet each host runs this same entrypoint; jax.distributed
-initialization is attempted automatically when the standard TPU env vars
-are present.
 """
 from __future__ import annotations
 
@@ -17,6 +14,7 @@ import jax
 
 from repro.configs import registry
 from repro.configs.base import TrainConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import Trainer
 
 
@@ -37,6 +35,7 @@ def main() -> None:
     ap.add_argument("--grad-compress", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (registry.reduced_config(args.arch) if args.reduced
            else registry.get_config(args.arch))

@@ -33,19 +33,13 @@ from .layers import Params, dense_init, mlp, mlp_init
 
 
 def _ambient_axis_size(axis) -> int:
-    """Size of a mesh axis from the ambient `with mesh:` context (1 if
+    """Size of a mesh axis from the ambient ``jax.set_mesh`` context (1 if
     no mesh / unknown axis — pins become no-risk no-ops)."""
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if not pm.empty:
-            total = 1
-            for a in (axis if isinstance(axis, tuple) else (axis,)):
-                total *= dict(pm.shape).get(a, 1)
-            return total
-    except Exception:  # noqa: BLE001 — defensive: pins are advisory
-        pass
-    return 1
+    pm = jax.sharding.get_abstract_mesh()
+    total = 1
+    for a in (axis if isinstance(axis, tuple) else (axis,)):
+        total *= dict(pm.shape).get(a, 1)
+    return total
 
 
 class MoESpec(NamedTuple):

@@ -61,8 +61,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import cache_pspecs, named
 from repro.kernels import dispatch, tiling
 from repro.models.transformer import (encoder_apply, init_caches,
                                       init_paged_caches, lm_apply,
@@ -250,7 +252,7 @@ class ServeEngine:
         self.eos_id = eos_id
         self.dtype = dtype
         # optional device mesh: per-phase resolution AND the compiled
-        # programs trace under `with mesh:`, so a cfg with ring_axis set
+        # programs trace under `jax.set_mesh(mesh)`, so a cfg with ring_axis set
         # resolves long-context prefill to the sequence-parallel ring
         # path (decode stays s_q=1 -> naive/flash_decode) and the
         # flash_ring provider finds the same mesh ambient at trace time
@@ -307,6 +309,14 @@ class ServeEngine:
         else:
             self.pool = None
             self.caches = init_caches(cfg, n_slots, max_seq, dtype)
+        if mesh is not None:
+            # state lives on the whole mesh: params replicate, KV caches
+            # shard their sequence over the ring axis (the layout the
+            # ring prefill rotates), never all on the first device
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
+            self.caches = jax.device_put(self.caches, named(
+                mesh, cache_pspecs(self.caches, mesh, n_slots,
+                                   ring_axis=cfg.ring_axis or None)))
 
         # per-phase attention impls, resolved once through the dispatch
         # registry at each phase's representative shape (prefill: widest
@@ -379,7 +389,7 @@ class ServeEngine:
                       "starved": []}
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else (
+        return jax.set_mesh(self.mesh) if self.mesh is not None else (
             contextlib.nullcontext())
 
     # ---- host-side bookkeeping ----

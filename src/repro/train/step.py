@@ -203,7 +203,7 @@ def make_train_state(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
         ef = ef_state_init(params) if tcfg.grad_compress else {}
         return TrainState(params, adamw_init(params), ef)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = jax.jit(build, out_shardings=shardings)()
     return state, shardings
 

@@ -102,7 +102,7 @@ class Trainer:
             batch = {"tokens": jax.device_put(tokens, self._bsharding),
                      "labels": jax.device_put(labels, self._bsharding)}
             t0 = time.perf_counter()
-            with self.mesh:     # sharding constraints resolve at trace time
+            with jax.set_mesh(self.mesh):   # sharding constraints resolve at trace time
                 self.state, metrics = self.step_fn(self.state, batch)
             metrics = {k: float(v) for k, v in metrics.items()}
             dt = time.perf_counter() - t0

@@ -14,6 +14,7 @@ the case's sequence dims: a size-1 ring in the plain tier-1 run, the
 real 8-wide rotation under the CI multi-device lane
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 """
+import contextlib
 import functools
 
 import jax
@@ -61,19 +62,26 @@ def _case(name):
             str(dtype))
 
 
+def _ring_mesh(impl, q, k):
+    """The mesh context ``impl`` runs under: a ring as wide as the visible
+    devices allow (both sequence dims must divide) for flash_ring, none
+    otherwise.  Entered outside any trace — jax.set_mesh is refused
+    inside jit/grad."""
+    if impl != "flash_ring":
+        return contextlib.nullcontext()
+    s, t = q.shape[1], k.shape[1]
+    n = len(jax.devices())
+    while n > 1 and (s % n or t % n):
+        n //= 2
+    return jax.set_mesh(auto_mesh((n,), ("model",)))
+
+
 def _run(impl, q, k, v, q_pos, kv_valid, causal):
     fn = dispatch.get_attention(impl)
     call = functools.partial(fn, q_pos=q_pos, kv_valid=kv_valid,
                              causal=causal, scale=None,
                              softmax_impl="float", ring_axis="model")
-    if impl != "flash_ring":
-        return call(q, k, v)
-    s, t = q.shape[1], k.shape[1]
-    n = len(jax.devices())
-    while n > 1 and (s % n or t % n):
-        n //= 2
-    with auto_mesh((n,), ("model",)):
-        return call(q, k, v)
+    return call(q, k, v)
 
 
 @pytest.mark.parametrize("impl", FLOAT_IMPLS)
@@ -81,7 +89,8 @@ def _run(impl, q, k, v, q_pos, kv_valid, causal):
 def test_outputs_match_naive(case, impl):
     q, k, v, q_pos, kv_valid, causal, dtype = _case(case)
     want = _run("naive", q, k, v, q_pos, kv_valid, causal)
-    got = _run(impl, q, k, v, q_pos, kv_valid, causal)
+    with _ring_mesh(impl, q, k):
+        got = _run(impl, q, k, v, q_pos, kv_valid, causal)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -98,7 +107,8 @@ def test_grads_match_naive(case, impl):
             lambda q_, k_, v_: f(q_, k_, v_).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    got = g_of(lambda *a: _run(impl, *a, q_pos, kv_valid, causal))
+    with _ring_mesh(impl, q, k):
+        got = g_of(lambda *a: _run(impl, *a, q_pos, kv_valid, causal))
     want = g_of(lambda *a: _run("naive", *a, q_pos, kv_valid, causal))
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))), name
@@ -233,7 +243,7 @@ def test_dualmode_ring_row(case):
     n = len(jax.devices())
     while n > 1 and (s % n or t % n):
         n //= 2
-    with auto_mesh((n,), ("model",)):
+    with jax.set_mesh(auto_mesh((n,), ("model",))):
         got = ring_flash_attention(q, k, v, q_pos=q_pos,
                                    kv_valid=kv_valid, causal=causal,
                                    softmax_impl="dualmode")

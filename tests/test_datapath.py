@@ -214,6 +214,37 @@ def test_tiling_blocks_never_degenerate():
     assert bm % tiling.SUBLANE == 0 and bf % tiling.LANE == 0
 
 
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, want):
+    """Kernels interpret on the CPU backend, compile on TPU, and refuse
+    any other backend instead of silently interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            tiling.interpret_mode()
+    else:
+        assert tiling.interpret_mode() is want
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", tiling.DECODE_MAX_SPLITS),
+    ("tpu", "TPU v5 lite", 1),
+    ("tpu", "TPU v99", None),
+])
+def test_device_core_count_knows_v5e_and_refuses_guesses(
+        monkeypatch, platform, kind, want):
+    class Dev:
+        device_kind = kind
+    Dev.platform = platform
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    if want is None:
+        with pytest.raises(RuntimeError, match="TPU v99"):
+            tiling.device_core_count()
+    else:
+        assert tiling.device_core_count() == want
+
+
 # ---------------- (e) online_softmax_merge: the ring monoid ----------------
 # The algebraic fact sequence-parallel ring attention relies on: partial
 # (m, l, acc) states form a commutative monoid under the merge, with the

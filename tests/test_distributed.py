@@ -173,3 +173,17 @@ ENTRY %main (a: f32[8,64]) -> f32[64,64] {
     sizes = collective_result_bytes(txt, "all-gather")
     assert sorted(sizes) == [64 * 64 * 4, 64 * 64 * 4]
     assert collective_result_bytes(txt, "all-reduce") == []
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Published v5e peaks (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+    ICI); a device kind without published peaks is an error."""
+    import pytest
+
+    from repro.launch.hlo_analysis import device_peaks, roofline_terms
+    p = device_peaks("TPU v5 lite")
+    assert (p["flops"], p["hbm_bw"], p["ici_bw"]) == (197e12, 819e9, 200e9)
+    r = roofline_terms(197e12, 819e9 * 2, 0.0, device_kind="TPU v5 lite")
+    assert r["bottleneck"] == "memory" and r["t_bound"] == 2.0
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("TPU v99")

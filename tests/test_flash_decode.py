@@ -130,7 +130,7 @@ def test_auto_decode_pick_is_mesh_gated():
     from repro.launch.mesh import auto_mesh
     assert dispatch.resolve_attention("auto", 1, 65536) == "flash_decode"
     mesh = auto_mesh((len(jax.devices()),), ("model",))
-    with mesh:
+    with jax.set_mesh(mesh):
         assert dispatch.resolve_attention("auto", 1, 65536) == "naive"
     assert dispatch.resolve_attention("auto", 1, 65536) == "flash_decode"
 

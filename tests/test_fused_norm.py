@@ -370,7 +370,7 @@ def test_block_fused_respects_megatron_pins():
     fused_cfg = dataclasses.replace(cfg, norm_impl="fused_pallas")
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("model",))
     pspec = P(None, "model", None)
-    with mesh:
+    with jax.set_mesh(mesh):
         out_d = lm_apply(params, cfg, toks,
                          act_pspec=pspec, inner_pins=True)[0]
         out_f = lm_apply(params, fused_cfg, toks,

@@ -149,7 +149,7 @@ def test_resolve_ring_upgrade_is_mesh_and_knob_gated():
     # no ambient mesh -> never ring, knob or not
     assert dispatch.resolve_attention(
         "auto", 4096, 4096, ring_axis="model") == "flash"
-    with mesh:
+    with jax.set_mesh(mesh):
         got = dispatch.resolve_attention("auto", 4096, 4096,
                                          ring_axis="model")
         assert got == ("flash_ring" if n > 1 else "flash")
@@ -186,7 +186,7 @@ def test_explicit_ring_plus_dualmode_resolves_and_matches():
     q, k, v = _mk(b, s, t, 2, 2, 16)
     q_pos = jnp.broadcast_to(jnp.arange(s)[None] + (t - s), (b, s))
     kv_valid = jnp.ones((b, t), bool)
-    with mesh:
+    with jax.set_mesh(mesh):
         got = ring_flash_attention(q, k, v, q_pos=q_pos,
                                    kv_valid=kv_valid,
                                    softmax_impl="dualmode")
@@ -232,7 +232,7 @@ def test_gqa_layer_forward_through_ring_matches_naive():
     p = gqa_init(jax.random.PRNGKey(0), spec_ring, jnp.float32)
     x = jnp.asarray(RNG.normal(size=(2, 32, 32)), jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(32)[None], (2, 32))
-    with mesh:
+    with jax.set_mesh(mesh):
         got, _ = gqa_apply(p, spec_ring, x, positions=positions)
     want, _ = gqa_apply(p, spec_naive, x, positions=positions)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

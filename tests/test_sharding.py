@@ -145,7 +145,7 @@ sh = jax.tree.map(lambda s: NamedSharding(mesh, s), spec,
 state_sh = jax.device_put(state, sh)
 bsh = NamedSharding(mesh, P("data", None))
 batch_sh = jax.tree.map(lambda x: jax.device_put(x, bsh), batch)
-with mesh:
+with jax.set_mesh(mesh):
     step = jax.jit(make_train_step(cfg, tcfg, mesh),
                    in_shardings=(sh, bsh), out_shardings=(sh, None))
     s2, m2 = step(state_sh, batch_sh)
@@ -191,7 +191,7 @@ sh = jax.tree.map(lambda s: NamedSharding(mesh, s), spec,
 state_sh = jax.device_put(state, sh)
 bsh = NamedSharding(mesh, P("data", None))
 batch_sh = jax.tree.map(lambda x: jax.device_put(x, bsh), batch)
-with mesh:
+with jax.set_mesh(mesh):
     _, m2 = jax.jit(make_train_step(cfg, tcfg, mesh),
                     in_shardings=(sh, bsh), out_shardings=(sh, None)
                     )(state_sh, batch_sh)

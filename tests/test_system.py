@@ -54,3 +54,20 @@ def test_train_checkpoint_serve_lifecycle(tmp_path):
             total += 1
     rate = hits / total
     assert rate > 0.35, f"served tokens ignore learned bigram: {rate:.2f}"
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, stands untouched; otherwise
+    the cache goes to the fixed <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+    assert (compile_cache.CHECKOUT / "chip_smoke.py").is_file()
+    assert calls == [("jax_compilation_cache_dir", path)]
