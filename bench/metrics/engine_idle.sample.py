@@ -1,0 +1,8 @@
+"""Share of the time inside ``eng.step()`` spans in which the device idled
+while the engine was in ``engine.sample`` (per-slot sampling, its int()
+pulls and the token feedback), from the trace.  Moves ``itl_p95_ms``."""
+from bench import phases
+
+
+def read(run):
+    return phases.engine_idle(run, "engine.sample")

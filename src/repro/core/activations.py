@@ -55,18 +55,21 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def _ste(fwd_quant: Callable, surrogate: Callable) -> Callable:
+def _ste(fwd_quant: Callable, surrogate: Callable, name: str) -> Callable:
     """Straight-through wrapper: forward bits, backward surrogate grad.
-    The unit computes in f32 words; the result keeps the input's dtype."""
+    The unit computes in f32 words; the result keeps the input's dtype.
+    Its ops carry ``name`` in their ``op_name`` (a profiler trace shows
+    them under it)."""
     def f(x):
-        q = fwd_quant(x).astype(x.dtype)
-        return surrogate(x) + jax.lax.stop_gradient(q - surrogate(x))
+        with jax.named_scope(name):
+            q = fwd_quant(x).astype(x.dtype)
+            return surrogate(x) + jax.lax.stop_gradient(q - surrogate(x))
     return f
 
 
-gelu_dualmode = _ste(_unit.gelu_dualmode, gelu_tanh)
-silu_dualmode = _ste(_unit.silu_dualmode, silu)
-igelu_st = _ste(_igelu.igelu_quant, gelu_tanh)
+gelu_dualmode = _ste(_unit.gelu_dualmode, gelu_tanh, "unit.gelu_dualmode")
+silu_dualmode = _ste(_unit.silu_dualmode, silu, "unit.silu_dualmode")
+igelu_st = _ste(_igelu.igelu_quant, gelu_tanh, "unit.igelu")
 
 
 ACTIVATIONS: dict[str, Callable] = {

@@ -852,62 +852,80 @@ class ServeEngine:
         return len(self._queue) + self.active
 
     # ---- one engine step = admit + prefill chunk + one lockstep decode ----
+    #
+    # A step is four consecutive profiler spans that between them hold
+    # every statement, so a device trace puts each idle gap of the chip
+    # down to the host work that held it:
+    #   engine.admit    fault hooks, table checks, deadlines, admission
+    #   engine.prefill  one chunk of the oldest prefilling slot (paged)
+    #   engine.decode   table growth, the decode dispatch and the (B,)
+    #                   isfinite pull, where the host waits on the device
+    #   engine.sample   per-slot sampling, token feedback and retirement
+    # A step in which no slot decodes ends inside engine.decode.  Outside
+    # a trace a span costs well under a microsecond.
 
     def step(self) -> None:
-        self.stats["engine_steps"] += 1
-        if self.cache_mode == "paged":
-            if self.faults is not None:
-                self.faults.corrupt_tables(self.stats["engine_steps"],
-                                           self._tables, self._slots)
-            self._validate_tables()
-        self._expire_running_deadlines()
-        self._admit()
-        if self.cache_mode == "paged":
-            self._prefill_tick()
-            self._grow_decode_tables()
-        decoding = [s.decoding for s in self._slots]
-        if not any(decoding):
-            return
-        pos = jnp.asarray([s.pos if s.decoding else 0
-                           for s in self._slots], jnp.int32)
-        with self._mesh_ctx():
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            self.stats["engine_steps"] += 1
             if self.cache_mode == "paged":
-                # non-decoding rows get all-sentinel tables: their writes
-                # land in block 0, never in a mid-prefill slot's blocks
-                masked = np.where(np.asarray(decoding)[:, None],
-                                  self._tables, 0)
-                logits, self.caches = self._decode(
-                    self.params, self.caches, self._last_tok, pos,
-                    jnp.asarray(masked))
-            else:
-                logits, self.caches = self._decode(
-                    self.params, self.caches, self._last_tok, pos)
-        if self.faults is not None:
-            logits = self.faults.decode_logits(
-                self.stats["engine_steps"],
-                [s.rid if s.decoding else -1 for s in self._slots], logits)
-        # numeric sentry: one (B,) host pull per tick.  A non-finite row
-        # quarantines ONLY that slot (reason 'numeric', blocks refunded);
-        # the per-slot sampling keys below are split from the step key by
-        # slot INDEX, so the neighbours' token streams are bitwise
-        # unaffected by the quarantine.
-        finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
-        self.stats["decode_steps"] += 1
-        self._key, k = jax.random.split(self._key)
-        keys = jax.random.split(k, self.n_slots)
-        for i, s in enumerate(self._slots):
-            if not s.decoding:
-                continue
-            if not bool(finite[i]):
-                self.stats["numeric"] += 1
-                self._finish_slot(i, "numeric")
-                continue
-            tok = int(sample_token(keys[i], logits[i], s.temperature))
-            s.out.append(tok)
-            s.pos += 1
-            s.remaining -= 1
-            self._last_tok = self._last_tok.at[i, 0].set(tok)
-            self._retire(i)
+                if self.faults is not None:
+                    self.faults.corrupt_tables(self.stats["engine_steps"],
+                                               self._tables, self._slots)
+                self._validate_tables()
+            self._expire_running_deadlines()
+            self._admit()
+        with jax.profiler.TraceAnnotation("engine.prefill"):
+            if self.cache_mode == "paged":
+                self._prefill_tick()
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            if self.cache_mode == "paged":
+                self._grow_decode_tables()
+            decoding = [s.decoding for s in self._slots]
+            if not any(decoding):
+                return
+            pos = jnp.asarray([s.pos if s.decoding else 0
+                               for s in self._slots], jnp.int32)
+            with self._mesh_ctx():
+                if self.cache_mode == "paged":
+                    # non-decoding rows get all-sentinel tables: their
+                    # writes land in block 0, never in a mid-prefill
+                    # slot's blocks
+                    masked = np.where(np.asarray(decoding)[:, None],
+                                      self._tables, 0)
+                    logits, self.caches = self._decode(
+                        self.params, self.caches, self._last_tok, pos,
+                        jnp.asarray(masked))
+                else:
+                    logits, self.caches = self._decode(
+                        self.params, self.caches, self._last_tok, pos)
+            if self.faults is not None:
+                logits = self.faults.decode_logits(
+                    self.stats["engine_steps"],
+                    [s.rid if s.decoding else -1 for s in self._slots],
+                    logits)
+            # numeric sentry: one (B,) host pull per tick.  A non-finite
+            # row quarantines ONLY that slot (reason 'numeric', blocks
+            # refunded); the per-slot sampling keys below are split from
+            # the step key by slot INDEX, so the neighbours' token streams
+            # are bitwise unaffected by the quarantine.
+            finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
+        with jax.profiler.TraceAnnotation("engine.sample"):
+            self.stats["decode_steps"] += 1
+            self._key, k = jax.random.split(self._key)
+            keys = jax.random.split(k, self.n_slots)
+            for i, s in enumerate(self._slots):
+                if not s.decoding:
+                    continue
+                if not bool(finite[i]):
+                    self.stats["numeric"] += 1
+                    self._finish_slot(i, "numeric")
+                    continue
+                tok = int(sample_token(keys[i], logits[i], s.temperature))
+                s.out.append(tok)
+                s.pos += 1
+                s.remaining -= 1
+                self._last_tok = self._last_tok.at[i, 0].set(tok)
+                self._retire(i)
 
     def run(self, requests: list[Request], max_steps: int = 10_000
             ) -> dict[int, list[int]]:

@@ -66,7 +66,7 @@ class Trainer:
             self.state, _ = make_train_state(cfg, tcfg, self.mesh,
                                              dtype=dtype)
         # telemetry
-        self.step_times: list[float] = []
+        self._steps_watched = 0
         self.straggler_steps: list[int] = []
         self._ema: float | None = None
 
@@ -94,34 +94,49 @@ class Trainer:
     # ---------------- main loop ----------------
 
     def run(self, n_steps: int | None = None) -> dict[str, Any]:
+        """Train to ``total_steps``, or ``n_steps`` more.  Each step is
+        three consecutive profiler spans, so a device trace puts each
+        idle gap of the chip down to the host work that held it:
+        ``trainer.feed`` (make the batch, put it on the devices),
+        ``trainer.compute`` (dispatch the step and wait for its
+        metrics) and, on a step that saves, ``trainer.checkpoint`` (the
+        device-to-host copy of the state); the wait for the last save's
+        disk write at the end is a ``trainer.checkpoint`` span too."""
         end = self.tcfg.total_steps if n_steps is None \
             else self.start_step + n_steps
         metrics = {}
         for step in range(self.start_step, end):
-            tokens, labels = self.data.batch(step)
-            batch = {"tokens": jax.device_put(tokens, self._bsharding),
-                     "labels": jax.device_put(labels, self._bsharding)}
-            t0 = time.perf_counter()
-            with jax.set_mesh(self.mesh):   # sharding constraints resolve at trace time
-                self.state, metrics = self.step_fn(self.state, batch)
-            metrics = {k: float(v) for k, v in metrics.items()}
-            dt = time.perf_counter() - t0
-            self._watch_straggler(step, dt)
-            if (step + 1) % self.tcfg.checkpoint_every == 0:
-                self.save(step + 1, block=False)
-            if step % 10 == 0 or step == end - 1:
-                self.log(f"[trainer] step {step} loss={metrics['loss']:.4f} "
-                         f"gnorm={metrics['grad_norm']:.2f} {dt*1e3:.0f}ms")
-        self.store.wait()
+            with jax.profiler.TraceAnnotation("trainer.feed"):
+                tokens, labels = self.data.batch(step)
+                batch = {"tokens": jax.device_put(tokens, self._bsharding),
+                         "labels": jax.device_put(labels, self._bsharding)}
+            with jax.profiler.TraceAnnotation("trainer.compute"):
+                t0 = time.perf_counter()
+                with jax.set_mesh(self.mesh):   # sharding constraints resolve at trace time
+                    self.state, metrics = self.step_fn(self.state, batch)
+                metrics = {k: float(v) for k, v in metrics.items()}
+                dt = time.perf_counter() - t0
+                self._watch_straggler(step, dt)
+                if step % 10 == 0 or step == end - 1:
+                    self.log(f"[trainer] step {step} "
+                             f"loss={metrics['loss']:.4f} "
+                             f"gnorm={metrics['grad_norm']:.2f} "
+                             f"{dt*1e3:.0f}ms")
+                save = (step + 1) % self.tcfg.checkpoint_every == 0
+            if save:
+                with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+                    self.save(step + 1, block=False)
+        with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+            self.store.wait()
         self.start_step = end
         return metrics
 
     def _watch_straggler(self, step: int, dt: float) -> None:
-        self.step_times.append(dt)
+        self._steps_watched += 1
         if self._ema is None:
             self._ema = dt
             return
-        if dt > self.straggler_factor * self._ema and len(self.step_times) > 3:
+        if dt > self.straggler_factor * self._ema and self._steps_watched > 3:
             self.straggler_steps.append(step)
             self.log(f"[trainer] STRAGGLER step {step}: {dt*1e3:.0f}ms vs "
                      f"EMA {self._ema*1e3:.0f}ms")
