@@ -383,11 +383,12 @@ _PAGED_ATTENTION: dict[str, Callable] = {}
 
 
 def register_paged_attention(name: str, fn: Callable) -> None:
-    """fn(q, k_pool, v_pool, *, block_tables, q_pos, kv_valid, causal,
-    scale, softmax_impl, ring_axis) -> (B,1,K,G,hv).
+    """fn(q, k_pool, v_pool, *, block_tables, layer, q_pos, kv_valid,
+    causal, scale, softmax_impl, ring_axis) -> (B,1,K,G,hv).
 
-    ``k_pool``/``v_pool`` are (N_blocks, block_size, K, h) pools;
-    ``block_tables`` is a (B, max_blocks) int32 map from each row's
+    ``k_pool``/``v_pool`` are the stacked lane-dense pools
+    (L, N_blocks, block_size, K*h), read at layer ``layer`` (an int32
+    scalar); ``block_tables`` is a (B, max_blocks) int32 map from each row's
     logical block index to its pool block (sentinel block 0 for entries
     past the row's length).  Everything after the layout — masking,
     causality, the partial-merge fold — matches the dense contract."""

@@ -36,12 +36,16 @@ decode can never disagree with the other implementations on which keys
 are "off".  Forward-only: decode never differentiates.  Runs on CPU with
 ``interpret=True`` (the default on the CPU backend).
 
-Layout: the cache and the paged pool stay token-major; each grid step
-(batch row, split, kv tile) reads one (block_kv, K, h) block holding
+Layout: the contiguous cache stays token-major (B, T, K, h); each grid
+step (batch row, split, kv tile) reads one (block_kv, K, h) block holding
 EVERY kv head — the only block of that layout whose last two dims the
-TPU accepts — and loops over the heads inside the kernel.  Positions ride
-as scalar prefetch, per-tile validity as one whole-row block per batch
-row.
+TPU accepts — and loops over the heads inside the kernel.  The paged pool
+is lane-dense and stacked over layers, (L, N, bs, K*h): its TPU default
+layout is row-major, so the kernel reads it as it is stored, one
+(bs, K*h) block per grid step at (layer, table[b, tile]), and splits the
+heads by lane slices.  Positions, the block table and the layer index
+ride as scalar prefetch, per-tile validity as one whole-row block per
+batch row.
 
 DUAL-MODE decode (``softmax_impl='dualmode'``): the same split-KV grid
 runs the snapped-max INT recurrence instead — score words via
@@ -72,15 +76,24 @@ from .flash_attention import masked_score_block
 from .flash_attention_int import int_score_words, snap_tile_update
 
 
+def _kv_head(ref, h: int, width: int):
+    """(bkv, width) keys or values of kv head ``h`` from a token-major
+    (1, bkv, K, width) cache block or a lane-dense (1, bkv, K*width) pool
+    block (the head is then a static lane slice)."""
+    if len(ref.shape) == 4:
+        return ref[0, :, h, :]
+    return ref[0, :, h * width:(h + 1) * width]
+
+
 def _decode_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref, ol_ref,
                  oacc_ref, m_ref, l_ref, acc_ref, *, block_kv: int,
                  inner: int, causal: bool, t_kv: int):
     """One (batch, split, kv-tile) grid cell over EVERY kv head.
 
-    K/V blocks carry all heads (the last two block dims are then the full
-    (K, h) extents, which the TPU block rule accepts for the token-major
-    cache and pool); the head loop runs here, one (G, bkv) score tile per
-    head.  The kv-tile axis is innermost, so the (m, l, acc) VMEM scratch
+    K/V blocks carry all heads (the full (K, h) extents of the token-major
+    cache, or the full K*h lanes of the paged pool, both of which the TPU
+    block rule accepts); the head loop runs here, one (G, bkv) score tile
+    per head.  The kv-tile axis is innermost, so the (m, l, acc) VMEM scratch
     streams one split's tiles sequentially; at the split's last tile the
     UNNORMALIZED partial (m, l, acc = o·l) is written out for the n-way
     fold.
@@ -88,7 +101,7 @@ def _decode_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref, ol_ref,
     b = pl.program_id(0)
     sp = pl.program_id(1)
     kj = pl.program_id(2)
-    n_heads, g = q_ref.shape[2], q_ref.shape[3]
+    n_heads, g, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
     hv = oacc_ref.shape[-1]
     kv_tile = sp * inner + kj
     q_pos = qpos_ref[b]
@@ -105,8 +118,8 @@ def _decode_body(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref, ol_ref,
         valid = valid_ref[0, pl.ds(kv_tile, 1), :]         # (1, bkv)
         for h in range(n_heads):
             q = q_ref[0, 0, h].astype(jnp.float32)         # (G, h) pre-scaled
-            kb = k_ref[0, :, h, :].astype(jnp.float32)     # (bkv, h)
-            vb = v_ref[0, :, h, :].astype(jnp.float32)     # (bkv, hv)
+            kb = _kv_head(k_ref, h, hd).astype(jnp.float32)  # (bkv, h)
+            vb = _kv_head(v_ref, h, hv).astype(jnp.float32)  # (bkv, hv)
             s, _ = masked_score_block(q, kb, q_pos, valid, kv_tile,
                                       block_kv=block_kv, causal=causal,
                                       t_kv=t_kv)
@@ -141,7 +154,7 @@ def _decode_body_int(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref,
     b = pl.program_id(0)
     sp = pl.program_id(1)
     kj = pl.program_id(2)
-    n_heads, g = q_ref.shape[2], q_ref.shape[3]
+    n_heads, g, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
     hv = oacc_ref.shape[-1]
     nb = unit.N_SNAP_BUCKETS
     kv_tile = sp * inner + kj
@@ -158,8 +171,8 @@ def _decode_body_int(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref,
         valid = valid_ref[0, pl.ds(kv_tile, 1), :]         # (1, bkv)
         for h in range(n_heads):
             q = q_ref[0, 0, h].astype(jnp.float32)         # (G, h) pre-scaled
-            kb = k_ref[0, :, h, :].astype(jnp.float32)     # (bkv, h)
-            vb = v_ref[0, :, h, :].astype(jnp.float32)     # (bkv, hv)
+            kb = _kv_head(k_ref, h, hd).astype(jnp.float32)  # (bkv, h)
+            vb = _kv_head(v_ref, h, hv).astype(jnp.float32)  # (bkv, hv)
             sq = int_score_words(q, kb, q_pos, valid, kv_tile,
                                  block_kv=block_kv, causal=causal,
                                  t_kv=t_kv)
@@ -182,14 +195,15 @@ def _decode_body_int(qpos_ref, valid_ref, q_ref, k_ref, v_ref, om_ref,
         oacc_ref[0, 0] = acc_ref[:, :g, :hv]
 
 
-def _paged_body(body):
-    """Block-table wrapper of a decode body: the scalar-prefetched table
-    ref arrives first and is consumed entirely by the BlockSpec index
-    maps — the body proper is the SAME sweep as contiguous decode (the
-    physical gather happens in the pipeline, not the arithmetic)."""
-    def run(tab_ref, *refs, **kw):
-        del tab_ref
-        body(*refs, **kw)
+def _paged_body(body, n_index: int):
+    """Block-table wrapper of a decode body: the ``n_index``
+    scalar-prefetched refs that only address the pool (the layer index and
+    the block table) arrive first and are consumed entirely by the
+    BlockSpec index maps — the body proper is the SAME sweep as contiguous
+    decode (the physical gather happens in the pipeline, not the
+    arithmetic)."""
+    def run(*refs, **kw):
+        body(*refs[n_index:], **kw)
     return run
 
 
@@ -199,15 +213,17 @@ def _decode_call(q, k, v, q_pos, valid, *, kv_index, scalars, bkv: int,
     """The split-KV pallas_call shared by all four decode flavors.
 
     ``q`` (B, 1, K, G, h) pre-scaled f32; ``k``/``v`` a token-major cache
-    (B, T, K, h) or pool (N, bs, K, h) whose block of ``bkv`` keys over
-    all heads sits at ``kv_index(b, kv_tile, *scalars)``; ``valid`` the
+    (B, T, K, h) when ``scalars`` is empty, else the stacked lane-dense
+    pool (L, N, bs, K*h); the block of ``bkv`` keys over all heads sits at
+    ``kv_index(b, kv_tile, *scalars)``; ``valid`` the
     (B, num_splits * inner, bkv) per-tile validity rows; ``scalars`` the
     scalar-prefetch operands ahead of the per-row positions ``q_pos``
     (B,) int32.  ``guard_shift=None`` runs the float body, an int the
     dual-mode one.  Returns the per-split partials, split axis 1.
     """
     b, _, kh, g, hd = q.shape
-    hv = v.shape[-1]
+    paged = bool(scalars)
+    hv = v.shape[-1] // kh if paged else v.shape[-1]
     n_tiles = num_splits * inner
     int_mode = guard_shift is not None
     nb = unit.N_SNAP_BUCKETS
@@ -223,11 +239,16 @@ def _decode_call(q, k, v, q_pos, valid, *, kv_index, scalars, bkv: int,
     def part_map(b_, sp, kj, *pre):
         return (b_, sp, 0, 0, 0)
 
+    def kv_block(width):
+        # the pool's layer dim is squeezed: the body sees (1, bs, K*width)
+        return ((None, 1, bkv, kh * width) if paged
+                else (1, bkv, kh, width))
+
     in_specs = [
         pl.BlockSpec((1, n_tiles, bkv), row_map),
         pl.BlockSpec((1, 1, kh, g, hd), lambda b_, *r: (b_, 0, 0, 0, 0)),
-        pl.BlockSpec((1, bkv, kh, hd), kv_map),
-        pl.BlockSpec((1, bkv, kh, hv), kv_map),
+        pl.BlockSpec(kv_block(hd), kv_map),
+        pl.BlockSpec(kv_block(hv), kv_map),
     ]
     stat_lanes = nb if int_mode else 1
     stat_dtype = jnp.int32 if int_mode else jnp.float32
@@ -252,8 +273,8 @@ def _decode_call(q, k, v, q_pos, valid, *, kv_index, scalars, bkv: int,
                                  **kw)
     else:
         body = functools.partial(_decode_body, **kw)
-    if scalars:
-        body = _paged_body(body)
+    if paged:
+        body = _paged_body(body, len(scalars))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre, grid=(b, num_splits, inner),
         in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
@@ -302,10 +323,11 @@ def _flash_decode_jit(q, k, v, q_pos, kv_valid, scale, *, causal: bool,
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "num_splits", "interpret", "guard_shift"))
-def _flash_decode_paged_jit(q, k_pool, v_pool, tables, q_pos, kv_valid,
-                            scale, *, causal: bool, num_splits: int,
-                            interpret: bool, guard_shift: int | None):
-    bs = k_pool.shape[1]                 # block size == KV tile width
+def _flash_decode_paged_jit(q, k_pool, v_pool, tables, layer, q_pos,
+                            kv_valid, scale, *, causal: bool,
+                            num_splits: int, interpret: bool,
+                            guard_shift: int | None):
+    bs = k_pool.shape[2]                 # block size == KV tile width
     nblk = tables.shape[1]
     qf = q.astype(jnp.float32) * scale
     inner = tiling.cdiv(nblk, num_splits)
@@ -316,24 +338,29 @@ def _flash_decode_paged_jit(q, k_pool, v_pool, tables, q_pos, kv_valid,
     valid, _ = tiling.pad_dim(kv_valid.astype(jnp.int32), 1,
                               num_splits * inner * bs, value=0)
     # THE paged difference: the KV tile index routes through the
-    # scalar-prefetched block table instead of a contiguous stride
+    # scalar-prefetched layer index and block table instead of a
+    # contiguous stride
     parts = _decode_call(
         qf, k_pool, v_pool, q_pos, valid.reshape(q.shape[0], -1, bs),
-        kv_index=lambda b_, tile, tab_: (tab_[b_, tile], 0),
-        scalars=(tab,), bkv=bs, num_splits=num_splits, inner=inner,
+        kv_index=lambda b_, tile, lay, tab_: (lay[0], tab_[b_, tile]),
+        scalars=(jnp.reshape(layer, (1,)).astype(jnp.int32), tab),
+        bkv=bs, num_splits=num_splits, inner=inner,
         causal=causal, t_kv=nblk * bs, interpret=interpret,
         guard_shift=guard_shift)
     return _finish_decode(parts, v_pool.dtype, guard_shift is not None)
 
 
-def flash_decode_paged(q, k_pool, v_pool, *, block_tables, q_pos, kv_valid,
-                       causal: bool = True, scale: float | None = None,
+def flash_decode_paged(q, k_pool, v_pool, *, block_tables, layer, q_pos,
+                       kv_valid, causal: bool = True,
+                       scale: float | None = None,
                        num_splits: int | None = None,
                        interpret: bool | None = None,
                        softmax_impl: str = "float"):
     """Block-table flash decode: KV gathered through a paged pool.
 
-    ``k_pool``/``v_pool`` are (N_blocks, block_size, K, h|hv) pools and
+    ``k_pool``/``v_pool`` are the stacked lane-dense pools
+    (L, N_blocks, block_size, K*h|K*hv), read at layer ``layer`` (an int
+    or a traced int32 scalar, e.g. the layer scan's index), and
     ``block_tables`` is (B, max_blocks) int32 mapping each row's logical
     block index to its pool block (sentinel 0 past the row's length; the
     sentinel's mass is masked to exp(MASK_VALUE) by ``kv_valid`` exactly
@@ -350,7 +377,13 @@ def flash_decode_paged(q, k_pool, v_pool, *, block_tables, q_pos, kv_valid,
         raise ValueError(
             f"flash_decode is the s_q=1 decode kernel; got s_q={q.shape[1]}"
             " — use 'flash'/'flash_pallas' for wide query tiles")
-    nblk, bs = block_tables.shape[1], k_pool.shape[1]
+    kh = q.shape[2]
+    if (k_pool.ndim != 4 or k_pool.shape[-1] % kh
+            or v_pool.shape[-1] % kh):
+        raise ValueError(
+            f"pools must be (layers, blocks, block_size, {kh} kv heads x "
+            f"head dim); got {k_pool.shape} and {v_pool.shape}")
+    nblk, bs = block_tables.shape[1], k_pool.shape[2]
     if kv_valid.shape[1] != nblk * bs:
         raise ValueError(
             f"kv_valid covers {kv_valid.shape[1]} keys but the table maps "
@@ -364,7 +397,8 @@ def flash_decode_paged(q, k_pool, v_pool, *, block_tables, q_pos, kv_valid,
     # dual-mode guard from the LOGICAL cache extent, as the whole-row
     # unit would apply it
     guard_shift = _guard_shift(softmax_impl, nblk * bs, "flash_decode_paged")
-    return _flash_decode_paged_jit(q, k_pool, v_pool, block_tables, q_pos,
+    return _flash_decode_paged_jit(q, k_pool, v_pool, block_tables,
+                                   jnp.asarray(layer, jnp.int32), q_pos,
                                    kv_valid, jnp.float32(scale),
                                    causal=causal, num_splits=num_splits,
                                    interpret=interpret,
@@ -430,9 +464,10 @@ def _key_bytes(kh: int, hd: int, hv: int, dtype) -> int:
 def vmem_plan(t_kv: int, hd: int, hv: int, g: int = 1, kh: int = 1):
     """Static VMEM residency of the four decode kernels (see
     ``flash_attention.vmem_plan`` for the contract).  The paged variants
-    tile by the engine's block size instead of the split-KV block; the
-    scalar-prefetched positions and block table live in SMEM, not VMEM,
-    so they do not appear here."""
+    tile by the engine's block size instead of the split-KV block and read
+    lane-dense (bs, K*h) pool blocks; the scalar-prefetched positions,
+    layer index and block table live in SMEM, not VMEM, so they do not
+    appear here."""
     num_splits = tiling.decode_splits(t_kv)
     bkv = tiling.decode_kv_block(t_kv, num_splits,
                                  _key_bytes(kh, hd, hv, jnp.float32))
@@ -440,14 +475,16 @@ def vmem_plan(t_kv: int, hd: int, hv: int, g: int = 1, kh: int = 1):
     rows = tiling.round_up(g, tiling.SUBLANE)
     nb = unit.N_SNAP_BUCKETS
 
-    def plan(block, n_tiles, int_mode):
+    def plan(block, n_tiles, int_mode, paged=False):
         stat = jnp.int32 if int_mode else jnp.float32
         lanes = nb if int_mode else 1
+        kv = ((lambda w: (1, block, kh * w)) if paged
+              else (lambda w: (1, block, kh, w)))
         return {
             "in:kv_valid": ((1, n_tiles, block), jnp.int32),
             "in:q": ((1, 1, kh, g, hd), jnp.float32),
-            "in:k": ((1, block, kh, hd), jnp.float32),
-            "in:v": ((1, block, kh, hv), jnp.float32),
+            "in:k": (kv(hd), jnp.float32),
+            "in:v": (kv(hv), jnp.float32),
             "out:part_m": ((1, 1, kh, g, 1), stat),
             "out:part_l": ((1, 1, kh, g, lanes), stat),
             "out:part_acc": ((1, 1, kh, g, hv), jnp.float32),
@@ -462,8 +499,8 @@ def vmem_plan(t_kv: int, hd: int, hv: int, g: int = 1, kh: int = 1):
     return {
         "decode_float": plan(bkv, n_dense, False),
         "decode_int": plan(bkv, n_dense, True),
-        "decode_paged_float": plan(bs, n_paged, False),
-        "decode_paged_int": plan(bs, n_paged, True),
+        "decode_paged_float": plan(bs, n_paged, False, paged=True),
+        "decode_paged_int": plan(bs, n_paged, True, paged=True),
     }
 
 
@@ -478,14 +515,14 @@ def _attention_entry(q, k, v, *, q_pos, kv_valid, causal, scale,
                                softmax_impl=impl)
 
 
-def _paged_attention_entry(q, k_pool, v_pool, *, block_tables, q_pos,
-                           kv_valid, causal, scale, softmax_impl="float",
-                           ring_axis=""):
+def _paged_attention_entry(q, k_pool, v_pool, *, block_tables, layer,
+                           q_pos, kv_valid, causal, scale,
+                           softmax_impl="float", ring_axis=""):
     impl = ("dualmode" if softmax_impl in ("dualmode", "dualmode_snap")
             else "float")
     return flash_decode_paged(q, k_pool, v_pool, block_tables=block_tables,
-                              q_pos=q_pos, kv_valid=kv_valid, causal=causal,
-                              scale=scale, softmax_impl=impl)
+                              layer=layer, q_pos=q_pos, kv_valid=kv_valid,
+                              causal=causal, scale=scale, softmax_impl=impl)
 
 
 dispatch.register_attention(

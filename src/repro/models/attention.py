@@ -130,39 +130,44 @@ def _sdpa(q, k, v, *, q_pos, kv_valid, softmax_impl, causal=True,
         scale=scale, softmax_impl=softmax_impl, ring_axis=ring_axis)
 
 
-def _sdpa_paged(q, k_pool, v_pool, *, block_tables, q_pos, kv_valid,
-                softmax_impl, causal=True, scale: float | None = None,
-                attn_impl: str = "auto", ring_axis: str = ""):
-    """The paged twin of :func:`_sdpa`: K/V live in (N, bs, K, h) pools
-    addressed through (B, max_blocks) block tables.
+def _sdpa_paged(q, k_pool, v_pool, *, block_tables, layer, q_pos,
+                kv_valid, softmax_impl, causal=True,
+                scale: float | None = None, attn_impl: str = "auto",
+                ring_axis: str = ""):
+    """The paged twin of :func:`_sdpa`: K/V live in the stacked
+    lane-dense pools (L, N, bs, K*h) addressed through (B, max_blocks)
+    block tables, read at layer ``layer``.
 
     Resolution is the SAME dense rule at the logical cache extent —
     paged changes the memory layout, never the numerics pick.  When the
     resolved impl has a block-table native mode in the paged registry
-    (flash_decode's scalar-prefetch gather) the pools go to the kernel
-    untouched; otherwise K/V are gathered dense once and the dense impl
+    (flash_decode's scalar-prefetch gather) the whole stacked pools and
+    the layer index go to the kernel untouched; otherwise this layer's
+    blocks of the rows' tables are gathered dense once and the dense impl
     runs — identical words either way, the gather is pure data movement.
     """
-    s_q = q.shape[1]
-    t = block_tables.shape[1] * k_pool.shape[1]
+    b, s_q, kh = q.shape[:3]
+    t = block_tables.shape[1] * k_pool.shape[2]
     impl = dispatch.resolve_attention(attn_impl, s_q, t,
                                       softmax_impl=softmax_impl,
                                       ring_axis=ring_axis)
     fn = dispatch.get_paged_attention(impl) if s_q == 1 else None
     if fn is not None:
-        return fn(q, k_pool, v_pool, block_tables=block_tables, q_pos=q_pos,
-                  kv_valid=kv_valid, causal=causal, scale=scale,
+        return fn(q, k_pool, v_pool, block_tables=block_tables, layer=layer,
+                  q_pos=q_pos, kv_valid=kv_valid, causal=causal, scale=scale,
                   softmax_impl=softmax_impl, ring_axis=ring_axis)
+    k = paged_gather(k_pool, block_tables, layer).reshape(b, t, kh, -1)
+    v = paged_gather(v_pool, block_tables, layer).reshape(b, t, kh, -1)
     return dispatch.get_attention(impl)(
-        q, paged_gather(k_pool, block_tables),
-        paged_gather(v_pool, block_tables), q_pos=q_pos, kv_valid=kv_valid,
-        causal=causal, scale=scale, softmax_impl=softmax_impl,
-        ring_axis=ring_axis)
+        q, k, v, q_pos=q_pos, kv_valid=kv_valid, causal=causal, scale=scale,
+        softmax_impl=softmax_impl, ring_axis=ring_axis)
 
 
-def paged_write(pool, new, pos, block_tables):
-    """Scatter ``new`` (B,S,...) into the (N,bs,...) pool at logical
-    offset ``pos`` through each row's block table.
+def paged_write(pool, new, pos, block_tables, layer):
+    """Scatter ``new`` (B,S,...) into layer ``layer`` of the stacked
+    lane-dense pool (L, N, bs, W) at logical offset ``pos`` through each
+    row's block table; each new row's trailing dims flatten to the W
+    lanes (K*h for attention K/V).
 
     Logical position p of row b lands in pool block
     ``block_tables[b, p // bs]`` at offset ``p % bs``.  Positions past
@@ -170,8 +175,10 @@ def paged_write(pool, new, pos, block_tables):
     into sentinel block 0, which is never referenced by a valid key, so
     pad rows scatter harmlessly instead of corrupting live blocks.
     ``pos`` may be scalar or (B,), same contract as :func:`_write_seq`.
+    Only the B*S rows are written: inside the engine's layer scan, where
+    the pool is the carry, the scatter updates the buffer in place.
     """
-    n, bs = pool.shape[:2]
+    bs = pool.shape[2]
     b, sl = new.shape[:2]
     nblk = block_tables.shape[1]
     off0 = pos[:, None] if jnp.ndim(pos) else pos
@@ -180,21 +187,19 @@ def paged_write(pool, new, pos, block_tables):
     phys = jnp.take_along_axis(block_tables, jnp.clip(blk, 0, nblk - 1),
                                axis=1)
     phys = jnp.where((blk >= 0) & (blk < nblk), phys, 0)
-    flat = (phys * bs + off).reshape(-1)
-    pool_flat = pool.reshape((n * bs,) + pool.shape[2:])
-    pool_flat = pool_flat.at[flat].set(
-        new.astype(pool.dtype).reshape((b * sl,) + new.shape[2:]))
-    return pool_flat.reshape(pool.shape)
+    rows = new.astype(pool.dtype).reshape(b * sl, pool.shape[3])
+    return pool.at[layer, phys.reshape(-1), off.reshape(-1)].set(rows)
 
 
-def paged_gather(pool, block_tables):
-    """Materialize the dense (B, max_blocks*bs, ...) view of a paged
-    cache — the fallback for impls without a native block-table mode
-    (and the whole story for MLA, whose latent must expand densely
-    anyway before attention)."""
+def paged_gather(pool, block_tables, layer):
+    """Materialize the dense (B, max_blocks*bs, W) view of layer
+    ``layer`` of a stacked (L, N, bs, W) paged pool — only the rows'
+    table blocks are read.  The fallback for impls without a native
+    block-table mode (and the whole story for MLA, whose latent must
+    expand densely anyway before attention)."""
     b, nblk = block_tables.shape
-    dense = pool[block_tables]                 # (B, nblk, bs, ...)
-    return dense.reshape((b, nblk * pool.shape[1]) + pool.shape[2:])
+    dense = pool[layer, block_tables]          # (B, nblk, bs, W)
+    return dense.reshape(b, nblk * pool.shape[2], pool.shape[3])
 
 
 def _write_seq(buf, new, pos):
@@ -248,13 +253,14 @@ def gqa_cache_init(s: AttnSpec, batch: int, max_seq: int, dtype) -> Params:
 
 
 def gqa_apply(p: Params, s: AttnSpec, x, *, positions, cache=None, pos=0,
-              paged=None, prenorm=None):
+              paged=None, layer=0, prenorm=None):
     """x: (B,S,d).  If cache given: write new kv at `pos`, attend over cache.
     Returns (out, new_cache_or_None).
 
     ``paged`` (B, max_blocks) int32 block tables switches the cache from
-    contiguous (B, Smax, K, h) rows to (N, bs, K, h) pools: writes
-    scatter through the table, attention runs :func:`_sdpa_paged`.
+    contiguous (B, Smax, K, h) rows to the stacked lane-dense pools
+    (L, N, bs, K*h), used at layer ``layer``: writes scatter through the
+    table, attention runs :func:`_sdpa_paged`.
 
     ``prenorm=(norm_params, kind, eps, provider)`` hands this sublayer
     its own input norm (the block's norm1): with a fused provider and
@@ -293,13 +299,13 @@ def gqa_apply(p: Params, s: AttnSpec, x, *, positions, cache=None, pos=0,
         q = apply_rope(q, positions, s.rope_theta)
         k = apply_rope(k, positions, s.rope_theta)
     if paged is not None:
-        cache = {"k": paged_write(cache["k"], k, pos, paged),
-                 "v": paged_write(cache["v"], v, pos, paged)}
-        t = paged.shape[1] * cache["k"].shape[1]
+        cache = {"k": paged_write(cache["k"], k, pos, paged, layer),
+                 "v": paged_write(cache["v"], v, pos, paged, layer)}
+        t = paged.shape[1] * cache["k"].shape[2]
         kv_valid = _kv_valid_mask(t, pos, sl, b)
         qg = q.reshape(b, sl, s.n_kv_heads, g, s.head_dim)
         o = _sdpa_paged(qg, cache["k"], cache["v"], block_tables=paged,
-                        q_pos=positions, kv_valid=kv_valid,
+                        layer=layer, q_pos=positions, kv_valid=kv_valid,
                         softmax_impl=s.softmax_impl, causal=s.causal,
                         attn_impl=s.attn_impl, ring_axis=s.ring_axis)
         o = o.reshape(b, sl, s.n_heads * s.head_dim)
@@ -346,7 +352,7 @@ def mla_cache_init(s: MLASpec, batch: int, max_seq: int, dtype) -> Params:
 
 
 def mla_apply(p: Params, s: MLASpec, x, *, positions, cache=None, pos=0,
-              paged=None):
+              paged=None, layer=0):
     b, sl, _ = x.shape
     qk_head = s.nope_dim + s.rope_dim
     if s.q_lora_rank:
@@ -367,11 +373,11 @@ def mla_apply(p: Params, s: MLASpec, x, *, positions, cache=None, pos=0,
         # MLA pages the COMPRESSED latent + rope key; the latent must
         # expand densely before attention regardless, so the paged win is
         # pure storage — gather once, then the dense path is unchanged.
-        cache = {"ckv": paged_write(cache["ckv"], ckv, pos, paged),
+        cache = {"ckv": paged_write(cache["ckv"], ckv, pos, paged, layer),
                  "krope": paged_write(cache["krope"], k_rope_new, pos,
-                                      paged)}
-        ckv_all = paged_gather(cache["ckv"], paged)
-        krope_all = paged_gather(cache["krope"], paged)
+                                      paged, layer)}
+        ckv_all = paged_gather(cache["ckv"], paged, layer)
+        krope_all = paged_gather(cache["krope"], paged, layer)
         t = ckv_all.shape[1]
         kv_valid = _kv_valid_mask(t, pos, sl, b)
     elif cache is not None:

@@ -140,7 +140,8 @@ def flash_attention_paged_ref(q, k_pool, v_pool, *, block_tables, q_pos,
                               kv_valid, causal: bool = True,
                               scale: float | None = None):
     """Paged fold oracle: one python loop over LOGICAL blocks, each block
-    gathered from the pool through the table, scored+masked exactly like
+    gathered through the table from ONE layer's (N, bs, K, h|hv) pools
+    (the serving pools' layer ``l`` reshaped), scored+masked exactly like
     the dense paths, reduced to its ``(m, l, o·l)`` partial with
     :func:`repro.kernels.datapath.online_softmax_partial` and folded with
     :func:`repro.kernels.datapath.online_softmax_merge`.

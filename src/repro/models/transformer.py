@@ -134,9 +134,11 @@ class Ctx(NamedTuple):
     pin_full: Any = None      # callable | None: (dp, None, None)
     moe_axes: Any = None      # (dp_axis, ep_axis) for MoE dispatch pins
     # paged KV: (B, max_blocks) int32 block tables, or None (contiguous).
-    # When set, attention caches are (N, bs, ...) pools shared across
-    # requests and writes/reads route through the table (serve engine).
+    # When set, attention caches are (L, N, bs, W) pools stacked over
+    # layers, shared across requests; writes/reads route through the
+    # table (serve engine) at layer index `layer` of the stack.
     paged: Any = None
+    layer: Any = 0            # int32 scalar: this block's pool layer
 
 
 def _pin(ctx: Ctx, x, kind: str):
@@ -175,7 +177,8 @@ def block_apply(p: Params, cfg: ModelConfig, spec: LayerSpec, x, cache,
         o, kv = gqa_apply(p["mixer"], attn_spec(cfg), h,
                           positions=ctx.positions,
                           cache=cache.get("kv") if ctx.cached else None,
-                          pos=ctx.pos, paged=ctx.paged, prenorm=pn)
+                          pos=ctx.pos, paged=ctx.paged, layer=ctx.layer,
+                          prenorm=pn)
         if ctx.cached:
             new_cache["kv"] = kv
     elif spec.mixer == "mla":
@@ -183,7 +186,7 @@ def block_apply(p: Params, cfg: ModelConfig, spec: LayerSpec, x, cache,
         o, kv = mla_apply(p["mixer"], mla_spec(cfg), h,
                           positions=ctx.positions,
                           cache=cache.get("kv") if ctx.cached else None,
-                          pos=ctx.pos, paged=ctx.paged)
+                          pos=ctx.pos, paged=ctx.paged, layer=ctx.layer)
         if ctx.cached:
             new_cache["kv"] = kv
     elif spec.mixer == "mamba":
@@ -325,20 +328,18 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 
 def _block_paged_cache_init(cfg: ModelConfig, spec: LayerSpec,
-                            num_blocks: int, block_size: int,
-                            dtype) -> Params:
+                            n_layers: int, num_blocks: int,
+                            block_size: int, dtype) -> Params:
     c: Params = {}
+    shape = (n_layers, num_blocks, block_size)
     if spec.mixer == "attn":
         s = attn_spec(cfg)
-        shape = (num_blocks, block_size, s.n_kv_heads, s.head_dim)
-        c["kv"] = {"k": jnp.zeros(shape, dtype),
-                   "v": jnp.zeros(shape, dtype)}
+        kv = shape + (s.n_kv_heads * s.head_dim,)
+        c["kv"] = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
     elif spec.mixer == "mla":
         m = mla_spec(cfg)
-        c["kv"] = {"ckv": jnp.zeros((num_blocks, block_size,
-                                     m.kv_lora_rank), dtype),
-                   "krope": jnp.zeros((num_blocks, block_size, m.rope_dim),
-                                      dtype)}
+        c["kv"] = {"ckv": jnp.zeros(shape + (m.kv_lora_rank,), dtype),
+                   "krope": jnp.zeros(shape + (m.rope_dim,), dtype)}
     elif spec.mixer != "none" or spec.cross:
         raise ValueError(f"mixer {spec.mixer!r} has no paged cache form")
     return c
@@ -346,11 +347,18 @@ def _block_paged_cache_init(cfg: ModelConfig, spec: LayerSpec,
 
 def init_paged_caches(cfg: ModelConfig, num_blocks: int, block_size: int,
                       dtype=jnp.float32) -> Params:
-    """Paged twin of :func:`init_caches`: per-layer (N, bs, ...) pools.
+    """Paged twin of :func:`init_caches`: lane-dense pools stacked over
+    layers, (L, N, bs, W).
 
-    Every layer gets its own pool but all layers share ONE block table
-    per request (allocation is in lockstep across the stack), so the
-    serve engine threads a single (B, max_blocks) table through
+    Attention K and V pools hold W = K*h lanes per token (MLA: the latent
+    and the rope key), so the TPU's default layout is row-major and the
+    paged decode kernel reads a (bs, K*h) block as it is stored.  Each
+    pattern position stacks its ``n_periods`` layers (a prefix layer is a
+    stack of one); ``lm_apply`` carries the stacks through its layer scan
+    and hands each block its layer index, so a step writes only its new
+    rows in place.  All layers share ONE block table per request
+    (allocation is in lockstep across the stack), so the serve engine
+    threads a single (B, max_blocks) table through
     ``lm_apply(..., paged=tables)``.  Block 0 of every pool is the write
     sentinel — the allocator never hands it out."""
     if not paged_supported(cfg):
@@ -360,12 +368,12 @@ def init_paged_caches(cfg: ModelConfig, num_blocks: int, block_size: int,
     caches: Params = {}
     if cfg.prefix:
         caches["prefix"] = [
-            _block_paged_cache_init(cfg, s, num_blocks, block_size, dtype)
+            _block_paged_cache_init(cfg, s, 1, num_blocks, block_size, dtype)
             for s in cfg.prefix]
-    one = [_block_paged_cache_init(cfg, s, num_blocks, block_size, dtype)
-           for s in cfg.pattern]
-    caches["periods"] = jax.tree.map(
-        lambda a: jnp.zeros((cfg.n_periods,) + a.shape, a.dtype), one)
+    caches["periods"] = [
+        _block_paged_cache_init(cfg, s, cfg.n_periods, num_blocks,
+                                block_size, dtype)
+        for s in cfg.pattern]
     return caches
 
 
@@ -421,7 +429,9 @@ def lm_apply(params: Params, cfg: ModelConfig, tokens, *, pos=0,
     paged        : optional (B, max_blocks) int32 block tables — caches
                    are :func:`init_paged_caches` pools and attention
                    writes/reads route through the tables (serve engine's
-                   zero-copy admission path)
+                   zero-copy admission path); the stacked pools ride the
+                   layer scan's carry, not its xs/ys, so each layer
+                   updates its rows in place
     """
     _, norm = make_norm(cfg.norm)
     b, sl = tokens.shape
@@ -469,7 +479,22 @@ def lm_apply(params: Params, cfg: ModelConfig, tokens, *, pos=0,
             new_caches["prefix"].append(nc)
             aux_total = aux_total + aux
 
-    if cached:
+    if cached and paged is not None:
+        def body(carry, xs):
+            x, aux_acc, pools = carry
+            pp, layer = xs
+            lctx = ctx._replace(layer=layer)
+            new = []
+            for j, spec in enumerate(cfg.pattern):
+                x, nc, aux = block_apply(pp[j], cfg, spec, x, pools[j], lctx)
+                new.append(nc)
+            return (pin(x), aux_acc + aux, new), None
+
+        (x, aux_total, pools), _ = jax.lax.scan(
+            body, (x, aux_total, caches["periods"]),
+            (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+        new_caches["periods"] = pools
+    elif cached:
         def body(carry, xs):
             x, aux_acc = carry
             pp, pc = xs

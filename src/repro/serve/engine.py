@@ -4,10 +4,10 @@ contiguous) KV cache.
 Two cache modes, same host scheduler skeleton:
 
 ``paged`` (the default wherever the architecture supports it) — the
-vLLM-style layout: per-layer (N, block_size, ...) block pools shared by
-every request, one (B, max_blocks) int32 block table, and a host-side
-:class:`repro.serve.paged_cache.BlockPool` doing admission/retire as
-pure block alloc/free.  Three properties fall out:
+vLLM-style layout: lane-dense (layers, N, block_size, K*h) block pools
+shared by every request, one (B, max_blocks) int32 block table, and a
+host-side :class:`repro.serve.paged_cache.BlockPool` doing
+admission/retire as pure block alloc/free.  Three properties fall out:
 
   * zero-copy admission: a request is admitted by writing integers into
     its table row — no cache-tree splice, no row copy (``_splice_slot``
@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Any
 
@@ -118,7 +119,11 @@ def make_chunk_prefill_step(cfg: ModelConfig, act_pspec=None):
     One compiled shape serves every chunk of every prompt: position is a
     traced scalar, the table a traced operand.  ``last_idx`` picks the
     logits row (the chunk's last REAL token) — only the final chunk's
-    logits are consumed, the others are (1, V) throwaways."""
+    logits are consumed, the others are (1, V) throwaways.  Each layer
+    reads only the slot's blocks of its pool.  ``ServeEngine`` jits it
+    with the caches donated: the caches passed in are consumed, their
+    buffers become the returned caches, and only the chunk's rows are
+    written."""
     def prefill_chunk(params, caches, tokens, pos, tables, last_idx):
         logits, caches, _ = lm_apply(params, cfg, tokens, pos=pos,
                                      caches=caches, last_pos=last_idx,
@@ -132,7 +137,10 @@ def make_paged_decode_step(cfg: ModelConfig, act_pspec=None):
     (logits(B,V), caches) — the lockstep decode tick reading/writing
     K/V through per-slot block tables.  Rows that must not write (free
     slots, slots mid-prefill) are handed all-sentinel table rows, so
-    their scatter lands in block 0 and touches nothing live."""
+    their scatter lands in block 0 and touches nothing live.
+    ``ServeEngine`` jits it with the caches donated: the caches passed in
+    are consumed and updated in place (B new rows a layer), and the
+    kernel reads the stacked pools at the scan's layer index."""
     def decode(params, caches, tokens, pos, tables):
         logits, caches, _ = lm_apply(params, cfg, tokens, pos=pos,
                                      caches=caches, act_pspec=act_pspec,
@@ -155,6 +163,14 @@ def _splice_slot(full_tree, row_tree, slot: int):
         return jax.lax.dynamic_update_slice(full, one.astype(full.dtype),
                                             tuple(start))
     return jax.tree_util.tree_map_with_path(write, full_tree, row_tree)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _put_blocks(caches, idx, saved):
+    """Write swapped-out block rows back at blocks ``idx`` (axis 1 of
+    every stacked pool), in place: the caches passed in are consumed."""
+    return jax.tree.map(lambda leaf, rows: leaf.at[:, idx].set(
+        rows.astype(leaf.dtype)), caches, saved)
 
 
 def sample_token(key, logits, temperature: float):
@@ -367,8 +383,12 @@ class ServeEngine:
         decode_cfg = cfg.replace(attn_impl=self.decode_attn_impl,
                                  softmax_impl=self.decode_softmax_impl)
         if self.cache_mode == "paged":
-            self._prefill = jax.jit(make_chunk_prefill_step(prefill_cfg))
-            self._decode = jax.jit(make_paged_decode_step(decode_cfg))
+            # the pools are donated: each step updates them in place and
+            # the engine rebinds self.caches to the result
+            self._prefill = jax.jit(make_chunk_prefill_step(prefill_cfg),
+                                    donate_argnums=(1,))
+            self._decode = jax.jit(make_paged_decode_step(decode_cfg),
+                                   donate_argnums=(1,))
         else:
             self._prefill = jax.jit(make_prefill_step(prefill_cfg))
             self._decode = jax.jit(make_decode_step(decode_cfg))
@@ -645,28 +665,16 @@ class ServeEngine:
 
     def _swap_out(self, blocks: list[int]):
         """Gather the slot's block rows from every cache pool to host
-        numpy — the swap store.  Stacked-period leaves carry a leading
-        n_periods dim, so their block axis is 1."""
+        numpy — the swap store.  Every pool is stacked over layers, so
+        the block axis is 1."""
         idx = jnp.asarray(blocks, jnp.int32)
-
-        def take(path, leaf):
-            names = [str(getattr(e, "key", getattr(e, "idx", "")))
-                     for e in path]
-            axis = 1 if "periods" in names else 0
-            return np.asarray(jnp.take(leaf, idx, axis=axis))
-        return jax.tree_util.tree_map_with_path(take, self.caches)
+        return jax.tree.map(
+            lambda leaf: np.asarray(jnp.take(leaf, idx, axis=1)),
+            self.caches)
 
     def _swap_in(self, blocks: list[int], saved) -> None:
-        idx = jnp.asarray(blocks, jnp.int32)
-
-        def put(path, leaf, rows):
-            names = [str(getattr(e, "key", getattr(e, "idx", "")))
-                     for e in path]
-            if "periods" in names:
-                return leaf.at[:, idx].set(rows.astype(leaf.dtype))
-            return leaf.at[idx].set(rows.astype(leaf.dtype))
-        self.caches = jax.tree_util.tree_map_with_path(
-            put, self.caches, saved)
+        self.caches = _put_blocks(self.caches,
+                                  jnp.asarray(blocks, jnp.int32), saved)
 
     def _pick_victim(self, i: int) -> int | None:
         """Choose a slot to preempt so slot ``i`` can grow: lowest

@@ -1,6 +1,6 @@
 """Host-side block pool for the paged KV cache (vLLM-style).
 
-The device side is dumb on purpose: per-layer (N, block_size, ...) pools
+The device side is dumb on purpose: (layers, N, block_size, W) pools
 plus one (B, max_blocks) int32 block table threaded through
 ``lm_apply(..., paged=tables)``.  Everything stateful lives here, in
 plain python, outside every compiled program:

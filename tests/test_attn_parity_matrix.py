@@ -256,15 +256,18 @@ def test_dualmode_ring_row(case):
 
 # ---------------- paged decode: block-table gather rows ----------------
 # The same matrix cases re-run at s_q=1 through the BLOCK-TABLE kernel:
-# the dense cache is scattered into a shuffled physical pool and read
-# back through per-row tables.  Parity vs the naive oracle (dense cache)
-# pins that the gather-by-table is invisible to the numerics: masking is
-# logical-position-only, pad blocks carry no mass.
+# the dense cache is scattered into one layer of a shuffled physical,
+# stacked lane-dense pool (L, N, bs, K*h) whose other layers hold noise,
+# and read back through per-row tables at that layer.  Parity vs the
+# naive oracle (dense cache) pins that the gather-by-table and the layer
+# index are invisible to the numerics: masking is logical-position-only,
+# pad blocks carry no mass.
 
 PAGED_BS = 16
+PAGED_LAYERS = 3
 
 
-def _paged_case(name):
+def _paged_case(name, layer):
     q, k, v, q_pos, kv_valid, causal, dtype = _decode_case(name)
     b, t = k.shape[0], k.shape[1]
     nblk = -(-t // PAGED_BS)
@@ -276,50 +279,54 @@ def _paged_case(name):
     ids = rng.permutation(np.arange(1, 1 + b * nblk))
     tables = jnp.asarray(ids.reshape(b, nblk).astype(np.int32))
     n_pool = 1 + b * nblk
-    shp = lambda x: (n_pool, PAGED_BS) + x.shape[2:]
-    k_pool = jnp.zeros(shp(kp), kp.dtype)
-    v_pool = jnp.zeros(shp(vp), vp.dtype)
     flat = (jnp.take_along_axis(
         tables, jnp.arange(t_pad)[None, :] // PAGED_BS, axis=1)
         * PAGED_BS + jnp.arange(t_pad)[None, :] % PAGED_BS)
-    k_pool = k_pool.reshape((n_pool * PAGED_BS,) + kp.shape[2:]).at[
-        flat.reshape(-1)].set(kp.reshape((-1,) + kp.shape[2:])
-                              ).reshape(shp(kp))
-    v_pool = v_pool.reshape((n_pool * PAGED_BS,) + vp.shape[2:]).at[
-        flat.reshape(-1)].set(vp.reshape((-1,) + vp.shape[2:])
-                              ).reshape(shp(vp))
-    return (q, k_pool, v_pool, tables, q_pos, valid, causal, dtype,
+
+    def pool(x):
+        lanes = x.shape[2] * x.shape[3]
+        noise = rng.normal(size=(PAGED_LAYERS, n_pool * PAGED_BS, lanes))
+        rows = jnp.asarray(noise, x.dtype).at[layer, flat.reshape(-1)].set(
+            x.reshape(-1, lanes))
+        return rows.reshape(PAGED_LAYERS, n_pool, PAGED_BS, lanes)
+
+    return (q, pool(kp), pool(vp), tables, q_pos, valid, causal, dtype,
             k, v, kv_valid)
 
 
+@pytest.mark.parametrize("layer", (0, PAGED_LAYERS - 1))
 @pytest.mark.parametrize("n_splits", (1, 2, 4))
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_flash_decode_paged_outputs_match_naive(case, n_splits):
+def test_flash_decode_paged_outputs_match_naive(case, n_splits, layer):
     from repro.kernels.flash_decode import flash_decode_paged
     (q, k_pool, v_pool, tables, q_pos, valid, causal, dtype,
-     k, v, kv_valid) = _paged_case(case)
+     k, v, kv_valid) = _paged_case(case, layer)
     want = _run("naive", q, k, v, q_pos, kv_valid, causal)
     got = flash_decode_paged(q, k_pool, v_pool, block_tables=tables,
-                             q_pos=q_pos, kv_valid=valid, causal=causal,
-                             num_splits=n_splits)
+                             layer=layer, q_pos=q_pos, kv_valid=valid,
+                             causal=causal, num_splits=n_splits)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=ATOL[dtype])
 
 
+@pytest.mark.parametrize("layer", (0, PAGED_LAYERS - 1))
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_flash_decode_paged_matches_fold_oracle(case):
+def test_flash_decode_paged_matches_fold_oracle(case, layer):
     """Block-table kernel vs the pure-JAX paged fold
-    (models/flash.flash_attention_paged_ref) — the paged twin of the
-    merged-fold contract, exercised on the SAME shuffled tables."""
+    (models/flash.flash_attention_paged_ref, which reads one layer's
+    (N, bs, K, h) pool) — the paged twin of the merged-fold contract,
+    exercised on the SAME shuffled tables."""
     from repro.kernels.flash_decode import flash_decode_paged
     from repro.models.flash import flash_attention_paged_ref
     (q, k_pool, v_pool, tables, q_pos, valid, causal, dtype,
-     *_ ) = _paged_case(case)
+     k, v, _) = _paged_case(case, layer)
     got = flash_decode_paged(q, k_pool, v_pool, block_tables=tables,
-                             q_pos=q_pos, kv_valid=valid, causal=causal)
-    ref = flash_attention_paged_ref(q, k_pool, v_pool,
+                             layer=layer, q_pos=q_pos, kv_valid=valid,
+                             causal=causal)
+    one = lambda pool, x: pool[layer].reshape(pool.shape[1:3] + x.shape[2:])
+    ref = flash_attention_paged_ref(q, one(k_pool, k), one(v_pool, v),
                                     block_tables=tables, q_pos=q_pos,
                                     kv_valid=valid, causal=causal)
     np.testing.assert_allclose(np.asarray(got, np.float32),

@@ -15,7 +15,7 @@ import pytest
 
 from repro.configs import registry
 from repro.kernels import dispatch, tiling
-from repro.kernels.flash_decode import flash_decode_pallas
+from repro.kernels.flash_decode import flash_decode_paged, flash_decode_pallas
 from repro.models.attention import _naive_sdpa
 from repro.models.transformer import init_lm
 from repro.serve import Request, ServeEngine
@@ -32,11 +32,34 @@ def _mk(b, t, kh, g, h, hv=None, dtype=jnp.float32):
     return q, k, v
 
 
+def _decode(q, k, v, *, layer, bs=128, **kw):
+    """Contiguous decode when ``layer`` is None; else the paged kernel
+    with the cache scattered, block by shuffled block, into layer
+    ``layer`` of a stacked lane-dense (3, N, bs, K*h) pool whose other
+    layers hold noise."""
+    if layer is None:
+        return flash_decode_pallas(q, k, v, **kw)
+    b, t = k.shape[:2]
+    nblk = t // bs
+    ids = RNG.permutation(np.arange(1, 1 + b * nblk)).reshape(b, nblk)
+
+    def pool(x):
+        blocks = x.reshape(b * nblk, bs, -1)
+        noise = RNG.normal(size=(3, 1 + b * nblk) + blocks.shape[1:])
+        return jnp.asarray(noise, x.dtype).at[layer, ids.reshape(-1)].set(
+            blocks)
+    return flash_decode_paged(q, pool(k), pool(v),
+                              block_tables=jnp.asarray(ids, jnp.int32),
+                              layer=layer, **kw)
+
+
 # ---------------- kernel ----------------
 
-def test_ragged_slot_depths_match_naive():
+@pytest.mark.parametrize("layer", [None, 0, 2])
+def test_ragged_slot_depths_match_naive(layer):
     """Every batch row at its own cache depth — the continuous-batching
-    shape: the per-row causal tile skip must reproduce the naive mask."""
+    shape: the per-row causal tile skip must reproduce the naive mask,
+    from the contiguous cache and from any layer of the paged pool."""
     b, t = 4, 1024
     q, k, v = _mk(b, t, 2, 2, 16)
     # slot depths spread from nearly-empty to nearly-full bucket
@@ -44,8 +67,8 @@ def test_ragged_slot_depths_match_naive():
     kv_valid = jnp.arange(t)[None, :] <= q_pos          # (B, T) ragged
     want = _naive_sdpa(q, k, v, q_pos=q_pos, kv_valid=kv_valid)
     for ns in (1, 2, 4, 8):
-        got = flash_decode_pallas(q, k, v, q_pos=q_pos, kv_valid=kv_valid,
-                                  num_splits=ns)
+        got = _decode(q, k, v, layer=layer, q_pos=q_pos, kv_valid=kv_valid,
+                      num_splits=ns)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, err_msg=f"n_splits={ns}")
 
@@ -159,8 +182,10 @@ def test_explicit_flash_decode_dualmode_resolves_and_runs():
                                atol=2e-3)
 
 
-def test_dualmode_decode_split_invariance():
-    """The int monoid fold: WHERE the cache splits cannot change words."""
+@pytest.mark.parametrize("layer", [None, 1])
+def test_dualmode_decode_split_invariance(layer):
+    """The int monoid fold: WHERE the cache splits cannot change words,
+    from the contiguous cache or a layer of the paged pool."""
     b, t = 2, 1024
     q, k, v = _mk(b, t, 2, 2, 16)
     q_pos = jnp.asarray([[40], [1000]], jnp.int32)
@@ -168,8 +193,8 @@ def test_dualmode_decode_split_invariance():
     ref = flash_decode_pallas(q, k, v, q_pos=q_pos, kv_valid=kv_valid,
                               num_splits=1, softmax_impl="dualmode")
     for ns in (2, 4, 8):
-        got = flash_decode_pallas(q, k, v, q_pos=q_pos, kv_valid=kv_valid,
-                                  num_splits=ns, softmax_impl="dualmode")
+        got = _decode(q, k, v, layer=layer, q_pos=q_pos, kv_valid=kv_valid,
+                      num_splits=ns, softmax_impl="dualmode")
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=1e-6, err_msg=f"n_splits={ns}")
 

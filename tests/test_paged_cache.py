@@ -1,6 +1,8 @@
 """Paged KV cache: BlockPool allocator invariants (property-tested),
-pool write/gather round-trips, and the block-table flash-decode kernel's
-parity against the pure-JAX paged fold oracle and the dense paths.
+pool write/gather round-trips, the block-table flash-decode kernel's
+parity against the pure-JAX paged fold oracle and the dense paths on the
+stacked lane-dense pool (L, N, bs, K*h) read at several layers, and the
+engine's donation of the pool to its step programs.
 
 The allocator property test is hypothesis-compatible: when the
 `hypothesis` package is present the operation sequences are drawn by it;
@@ -145,35 +147,46 @@ def test_chain_hashes_left_context_sensitivity():
 
 # ---------------- pool write / gather ----------------
 
-def test_paged_write_gather_round_trip():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_write_gather_round_trip(layer):
     key = jax.random.PRNGKey(0)
-    bs, nblk, b = 8, 4, 3
-    pool = jnp.zeros((1 + b * nblk, bs, 2, 4), jnp.float32)
+    bs, nblk, b, n_layers = 8, 4, 3, 3
+    pool = jnp.zeros((n_layers, 1 + b * nblk, bs, 2 * 4), jnp.float32)
     # shuffled physical layout: logical order != physical order
     tables = jnp.asarray(np.random.RandomState(0).permutation(
         np.arange(1, 1 + b * nblk)).reshape(b, nblk).astype(np.int32))
     new = jax.random.normal(key, (b, 13, 2, 4))
-    pool = paged_write(pool, new, jnp.asarray([0, 3, 19]), tables)
-    dense = paged_gather(pool, tables)
+    pool = paged_write(pool, new, jnp.asarray([0, 3, 19]), tables, layer)
+    dense = paged_gather(pool, tables, layer)
     for i, off in enumerate([0, 3, 19]):
-        np.testing.assert_array_equal(np.asarray(dense[i, off:off + 13]),
-                                      np.asarray(new[i]))
+        np.testing.assert_array_equal(
+            np.asarray(dense[i, off:off + 13]),
+            np.asarray(new[i]).reshape(13, 2 * 4))
+    # only layer `layer` of the stack was written
+    others = np.delete(np.asarray(pool), layer, axis=0)
+    assert not others.any()
     # out-of-range rows (pos 19 + 13 == 32 == capacity) never touched
     # the sentinel guard: writing past the table clamps to block 0
-    over = paged_write(pool, new, jnp.asarray([25, 25, 25]), tables)
-    np.testing.assert_array_equal(np.asarray(paged_gather(over, tables)
-                                             [:, :25]),
+    over = paged_write(pool, new, jnp.asarray([25, 25, 25]), tables, layer)
+    np.testing.assert_array_equal(np.asarray(paged_gather(over, tables,
+                                                          layer)[:, :25]),
                                   np.asarray(dense[:, :25]))
 
 
 # ---------------- kernel parity ----------------
 
+N_LAYERS = 3
+
+
 def _mk_paged_case(seed, b, kh, g, hd, hv, nblk, bs, shuffle=True):
+    """q, stacked lane-dense K/V pools (N_LAYERS, N, bs, K*h|K*hv) with
+    different random data in every layer, shuffled tables, ragged
+    positions."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     n_pool = 1 + b * nblk
     q = jax.random.normal(ks[0], (b, 1, kh, g, hd))
-    k_pool = jax.random.normal(ks[1], (n_pool, bs, kh, hd))
-    v_pool = jax.random.normal(ks[2], (n_pool, bs, kh, hv))
+    k_pool = jax.random.normal(ks[1], (N_LAYERS, n_pool, bs, kh * hd))
+    v_pool = jax.random.normal(ks[2], (N_LAYERS, n_pool, bs, kh * hv))
     ids = np.arange(1, n_pool)
     if shuffle:
         ids = np.random.RandomState(seed).permutation(ids)
@@ -184,38 +197,56 @@ def _mk_paged_case(seed, b, kh, g, hd, hv, nblk, bs, shuffle=True):
     return q, k_pool, v_pool, tables, q_pos, kv_valid
 
 
+def _layer(pool, layer, kh):
+    """Layer ``layer`` of a stacked lane-dense pool as the (N, bs, K, h)
+    pool the fold oracle reads."""
+    return pool[layer].reshape(pool.shape[1:3] + (kh, -1))
+
+
+def _dense(pool, tables, layer, kh):
+    """The (B, T, K, h) cache the contiguous kernel reads."""
+    d = paged_gather(pool, tables, layer)
+    return d.reshape(d.shape[:2] + (kh, -1))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("num_splits", [1, 2, 4])
 @pytest.mark.parametrize("gqa", [(4, 1), (2, 3)])
-def test_flash_decode_paged_matches_oracle_and_dense(num_splits, gqa):
+def test_flash_decode_paged_matches_oracle_and_dense(num_splits, gqa, layer):
     """The block-table kernel == the pure-JAX paged fold oracle == the
     dense split-KV kernel fed a gathered cache — with PHYSICALLY
     SHUFFLED tables, so any confusion of physical block id with logical
-    position shows up as a mismatch."""
+    position shows up as a mismatch, and a layer index into a stack
+    whose every layer holds other keys."""
     kh, g = gqa
     q, k_pool, v_pool, tables, q_pos, kv_valid = _mk_paged_case(
         1, b=3, kh=kh, g=g, hd=16, hv=16, nblk=8, bs=16)
     got = flash_decode_paged(q, k_pool, v_pool, block_tables=tables,
-                             q_pos=q_pos, kv_valid=kv_valid,
+                             layer=layer, q_pos=q_pos, kv_valid=kv_valid,
                              num_splits=num_splits, interpret=True)
-    ref = flash_attention_paged_ref(q, k_pool, v_pool, block_tables=tables,
-                                    q_pos=q_pos, kv_valid=kv_valid)
+    ref = flash_attention_paged_ref(
+        q, _layer(k_pool, layer, kh), _layer(v_pool, layer, kh),
+        block_tables=tables, q_pos=q_pos, kv_valid=kv_valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
-    dense = flash_decode_pallas(q, paged_gather(k_pool, tables),
-                                paged_gather(v_pool, tables), q_pos=q_pos,
-                                kv_valid=kv_valid, num_splits=num_splits,
-                                interpret=True)
+    dense = flash_decode_pallas(q, _dense(k_pool, tables, layer, kh),
+                                _dense(v_pool, tables, layer, kh),
+                                q_pos=q_pos, kv_valid=kv_valid,
+                                num_splits=num_splits, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                atol=1e-5)
 
 
-def test_flash_decode_paged_mla_head_dims():
+@pytest.mark.parametrize("layer", [0, 1])
+def test_flash_decode_paged_mla_head_dims(layer):
     # MLA decode shape: shared latent head, hv != hd
     q, k_pool, v_pool, tables, q_pos, kv_valid = _mk_paged_case(
         2, b=2, kh=1, g=4, hd=24, hv=16, nblk=4, bs=16)
     got = flash_decode_paged(q, k_pool, v_pool, block_tables=tables,
-                             q_pos=q_pos, kv_valid=kv_valid, interpret=True)
-    ref = flash_attention_paged_ref(q, k_pool, v_pool, block_tables=tables,
-                                    q_pos=q_pos, kv_valid=kv_valid)
+                             layer=layer, q_pos=q_pos, kv_valid=kv_valid,
+                             interpret=True)
+    ref = flash_attention_paged_ref(
+        q, _layer(k_pool, layer, 1), _layer(v_pool, layer, 1),
+        block_tables=tables, q_pos=q_pos, kv_valid=kv_valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 
 
@@ -225,37 +256,42 @@ def test_flash_decode_paged_table_permutation_invariance():
     q, k_pool, v_pool, tables, q_pos, kv_valid = _mk_paged_case(
         3, b=2, kh=2, g=2, hd=16, hv=16, nblk=4, bs=16, shuffle=False)
     base = flash_decode_paged(q, k_pool, v_pool, block_tables=tables,
-                              q_pos=q_pos, kv_valid=kv_valid,
+                              layer=1, q_pos=q_pos, kv_valid=kv_valid,
                               interpret=True)
-    perm = np.random.RandomState(7).permutation(k_pool.shape[0] - 1) + 1
-    inv = np.zeros(k_pool.shape[0], np.int32)
-    inv[perm] = np.arange(1, k_pool.shape[0])
-    k2 = jnp.concatenate([k_pool[:1], k_pool[perm]], 0)
-    v2 = jnp.concatenate([v_pool[:1], v_pool[perm]], 0)
+    n = k_pool.shape[1]
+    perm = np.random.RandomState(7).permutation(n - 1) + 1
+    inv = np.zeros(n, np.int32)
+    inv[perm] = np.arange(1, n)
+    k2 = jnp.concatenate([k_pool[:, :1], k_pool[:, perm]], 1)
+    v2 = jnp.concatenate([v_pool[:, :1], v_pool[:, perm]], 1)
     t2 = jnp.asarray(inv)[tables]
-    moved = flash_decode_paged(q, k2, v2, block_tables=t2, q_pos=q_pos,
-                               kv_valid=kv_valid, interpret=True)
+    moved = flash_decode_paged(q, k2, v2, block_tables=t2, layer=1,
+                               q_pos=q_pos, kv_valid=kv_valid,
+                               interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(moved))
 
 
-def test_paged_registry_entry():
+@pytest.mark.parametrize("mode", ["dualmode", "float"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_registry_entry(layer, mode):
     fn = dispatch.get_paged_attention("flash_decode")
     assert fn is not None
     assert dispatch.get_paged_attention("naive") is None
     # dualmode on the paged entry runs the snapped int split path (ISSUE 7)
-    # and matches the dense dual-mode decode on the gathered cache exactly:
-    # same words, same split fold, block tables only change the addressing
+    # and matches the dense decode on the gathered cache tiled at the
+    # block size BIT FOR BIT: same words, same split fold, same per-head
+    # arithmetic — the lane-dense pool and the block tables only change
+    # the addressing
     q, k_pool, v_pool, tables, q_pos, kv_valid = _mk_paged_case(
-        4, b=1, kh=2, g=2, hd=16, hv=16, nblk=2, bs=16)
-    got = fn(q, k_pool, v_pool, block_tables=tables, q_pos=q_pos,
-             kv_valid=kv_valid, causal=True, scale=None,
-             softmax_impl="dualmode")
-    dense = flash_decode_pallas(q, paged_gather(k_pool, tables),
-                                paged_gather(v_pool, tables), q_pos=q_pos,
-                                kv_valid=kv_valid, interpret=True,
-                                softmax_impl="dualmode")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
-                               atol=1e-6)
+        4, b=2, kh=2, g=2, hd=16, hv=16, nblk=4, bs=16)
+    got = fn(q, k_pool, v_pool, block_tables=tables, layer=layer,
+             q_pos=q_pos, kv_valid=kv_valid, causal=True, scale=None,
+             softmax_impl=mode)
+    dense = flash_decode_pallas(q, _dense(k_pool, tables, layer, 2),
+                                _dense(v_pool, tables, layer, 2),
+                                q_pos=q_pos, kv_valid=kv_valid, block_kv=16,
+                                interpret=True, softmax_impl=mode)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
 
 
 # ---------------- engine fast path (paged) ----------------
@@ -287,4 +323,36 @@ def test_paged_engine_decode_routes_through_kernel():
                       cache_mode="contiguous", prefill_buckets=(8,)).run(
         [Request(rid=0, prompt=[1, 2, 3], max_new=3),
          Request(rid=1, prompt=[4, 5], max_new=3)])
+    assert out == ref
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "deepseek-v2-lite-16b"])
+def test_paged_steps_donate_the_pool(arch):
+    """The chunk-prefill and decode programs consume the caches they are
+    handed — every buffer of the caches passed in is deleted after the
+    step, the engine holds the updated pools — and the donated engine
+    still serves the contiguous engine's greedy tokens (deepseek's first
+    layer is a prefix pool, a stack of one)."""
+    cfg = registry.reduced_config(arch)
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    eng = ServeEngine(cfg, params, n_slots=2, max_seq=64,
+                      cache_mode="paged", prefill_chunk=16)
+    reqs = [Request(rid=0, prompt=list(range(3, 12)), max_new=4),
+            Request(rid=1, prompt=[5, 1, 4], max_new=5)]
+    eng.submit(reqs[0])
+    before = eng.caches
+    eng.step()                   # one prefill chunk, then a decode tick
+    assert eng.stats["prefill_chunks"] == 1
+    assert eng.stats["decode_steps"] == 1
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    before = eng.caches
+    eng.step()                   # decode only
+    assert eng.stats["prefill_chunks"] == 1
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.caches))
+    out = eng.run(reqs[1:])
+    ref = ServeEngine(cfg, params, n_slots=2, max_seq=64,
+                      cache_mode="contiguous", prefill_buckets=(16,)).run(
+        [Request(rid=r.rid, prompt=r.prompt, max_new=r.max_new)
+         for r in reqs])
     assert out == ref

@@ -13,10 +13,15 @@ the serving and training paths run: qwen1.5-0.5b (16 kv heads, head dim
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
 imports this file.  All chip descriptions live in this one file.
+
+The serve engine's paged step programs are compiled whole as well, with
+their caches donated, to pin that a step keeps the KV pool in place.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,16 +130,17 @@ def _decode(mode, kh=KV, g=G, hd=HD):
 
 def _decode_paged(mode, kh=KV, g=G, hd=HD):
     """The serve engine's paged pool at max_seq 4096: 8 slots x 32 blocks
-    of 128 tokens plus the sentinel block."""
+    of 128 tokens plus the sentinel block, lane-dense and stacked over 2
+    layers, read at a traced layer index."""
     def build(s):
-        def fn(q, k_pool, v_pool, tables, q_pos, valid):
+        def fn(q, k_pool, v_pool, tables, layer, q_pos, valid):
             return flash_decode.flash_decode_paged(
-                q, k_pool, v_pool, block_tables=tables, q_pos=q_pos,
-                kv_valid=valid, num_splits=1, softmax_impl=mode,
-                interpret=False)
-        return fn, (s((8, 1, kh, g, hd), F32), s((257, 128, kh, hd), BF16),
-                    s((257, 128, kh, hd), BF16), s((8, 32), I32),
-                    s((8, 1), I32), s((8, 4096), jnp.bool_))
+                q, k_pool, v_pool, block_tables=tables, layer=layer,
+                q_pos=q_pos, kv_valid=valid, num_splits=1,
+                softmax_impl=mode, interpret=False)
+        pool = s((2, 257, 128, kh * hd), BF16)
+        return fn, (s((8, 1, kh, g, hd), F32), pool, pool, s((8, 32), I32),
+                    s((), I32), s((8, 1), I32), s((8, 4096), jnp.bool_))
     return build
 
 
@@ -210,3 +216,86 @@ def test_kernel_compiles_for_v5e(spec, name):
     fn, args = CASES[name](spec)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------- the serve engine's step programs ----------------
+
+# a small pool that still exceeds the chip's 128 MiB of VMEM, as every
+# deployed pool does (the compiler stages a smaller one through VMEM
+# whole): 8 slots x 32 blocks of 128 tokens plus the sentinel, at
+# qwen1.5-0.5b widths over 2 layers, 135 MB for K and for V
+STEP_BLOCKS, STEP_SLOTS = 257, 8
+_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+          "dynamic-update-slice")
+
+
+def _step_program(phase, mode):
+    """(step fn, args) of the engine's paged chunk-prefill or decode
+    program at the impls it resolves on the chip."""
+    from repro.configs import registry
+    from repro.models.transformer import init_lm, init_paged_caches
+    from repro.serve.engine import (make_chunk_prefill_step,
+                                    make_paged_decode_step)
+    cfg = registry.get_config("qwen1.5-0.5b").replace(
+        n_layers=2, ffn_impl="fused_pallas", norm_impl="fused_pallas",
+        softmax_impl=mode)
+
+    def build(s):
+        def shapes(tree):
+            return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+        params = shapes(jax.eval_shape(
+            lambda: init_lm(jax.random.PRNGKey(0), cfg, BF16)))
+        caches = shapes(jax.eval_shape(
+            lambda: init_paged_caches(cfg, STEP_BLOCKS, 128, BF16)))
+        if phase == "prefill":
+            impl = "flash_pallas_int" if mode == "dualmode" else \
+                "flash_pallas"
+            fn = make_chunk_prefill_step(cfg.replace(attn_impl=impl))
+            return fn, (params, caches, s((1, 2048), I32), s((), I32),
+                        s((1, 32), I32), s((1,), I32))
+        fn = make_paged_decode_step(cfg.replace(attn_impl="flash_decode"))
+        return fn, (params, caches, s((STEP_SLOTS, 1), I32),
+                    s((STEP_SLOTS,), I32), s((STEP_SLOTS, 32), I32))
+    return build
+
+
+def _pool_moves(hlo: str, sizes: set) -> list:
+    """HLO instructions that yield a buffer of one of ``sizes`` elements
+    by a copy, a dynamic slice or a dynamic update (their fusions are
+    named after them)."""
+    bad = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (.*?) ([\w-]+)\(", line)
+        if not m:
+            continue
+        name, typ, op = m.groups()
+        got = {math.prod(int(d) for d in dims.split(",") if d)
+               for dims in re.findall(r"\[([\d,]*)\]", typ)}
+        if got & sizes and (op in _MOVES or any(
+                k in name for k in ("copy", "dynamic-slice",
+                                    "dynamic-update-slice"))):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("phase,mode", [("decode", "float"),
+                                        ("decode", "dualmode"),
+                                        ("prefill", "float")])
+def test_paged_step_keeps_the_pool_in_place(spec, monkeypatch, phase,
+                                            mode):
+    """The paged step programs as ``ServeEngine`` jits them, caches
+    donated: every cache buffer is aliased to the output, and no
+    instruction copies, slices or updates a whole pool or a whole layer
+    of one — a step writes only its new rows and reads only live
+    blocks."""
+    from repro.kernels import tiling
+    monkeypatch.setattr(tiling, "interpret_mode", lambda: False)
+    fn, args = _step_program(phase, mode)(spec)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    pools = jax.tree.leaves(args[1])
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in pools)
+    sizes = {a.size for a in pools} | {a.size // a.shape[0] for a in pools}
+    assert _pool_moves(hlo, sizes) == []
