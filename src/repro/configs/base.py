@@ -30,6 +30,18 @@ class MoECfg:
     # so expert parallelism divides the mesh; padded experts are zero-init
     # and unroutable (router has exactly n_experts outputs).  0 = no pad.
     ep_pad: int = 0
+    # gates: the top-k softmax scores renormalised to sum 1 (True) or
+    # taken as they are (DeepSeek-V2), then times routed_scale
+    norm_topk_prob: bool = True
+    routed_scale: float = 1.0
+    # the router's product and the gates in float32 (DeepSeek-V2's gate);
+    # False keeps them in the activation dtype
+    router_f32: bool = False
+    # the experts this layer holds: [first_held, first_held + n_held) of
+    # the n_experts the router scores (0 = all of them).  One chip's share
+    # of an expert-parallel deployment; the absent experts add nothing.
+    first_held: int = 0
+    n_held: int = 0
 
 
 @dataclass(frozen=True)
@@ -39,6 +51,23 @@ class MLACfg:
     nope_dim: int
     rope_dim: int
     v_dim: int
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rotary scaling as DeepSeek-V2 publishes it (``rope_scaling``
+    with ``type: yarn``): frequencies blended between ``1/theta^(2i/d)``
+    and the same over ``factor`` by a linear ramp over the dimensions
+    whose rotations lie between ``beta_slow`` and ``beta_fast`` within
+    ``original_max_pos`` positions; cos/sin scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, and
+    MLA's softmax scale by ``mscale(factor, mscale_all_dim)**2``."""
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -69,6 +98,7 @@ class ModelConfig:
     norm: str = "rms"         # rms | layer
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    rope_yarn: YarnCfg | None = None   # YaRN scaling of MLA's rope key
     use_rope: bool = True
     pos_emb: str = "rope"     # rope | learned | sinusoid
     max_seq: int = 1 << 20    # learned-pos table size cap / cache bound

@@ -20,7 +20,7 @@ from repro.kernels import flash_decode as _pallas_decode        # noqa: F401
 from repro.kernels import ring_attention as _pallas_ring        # noqa: F401
 from . import flash as _flash                                   # noqa: F401
 from .layers import (Params, apply_rope, layernorm, linear, linear_init,
-                     rmsnorm, rmsnorm_init)
+                     rmsnorm, rmsnorm_init, yarn_mscale)
 
 
 class AttnSpec(NamedTuple):
@@ -59,6 +59,8 @@ class MLASpec(NamedTuple):
     ring_axis: str = ""
     # eps for the q/kv latent rmsnorms — carries cfg.norm_eps
     norm_eps: float = 1e-6
+    # YaRN scaling of the rope key (configs.base.YarnCfg) or None
+    yarn: object = None
 
 
 # ---------------- shared core ----------------
@@ -351,8 +353,57 @@ def mla_cache_init(s: MLASpec, batch: int, max_seq: int, dtype) -> Params:
             "krope": jnp.zeros((batch, max_seq, s.rope_dim), dtype)}
 
 
+def mla_softmax_scale(s: MLASpec) -> float:
+    """``1/sqrt(q.k head dim)``, times ``mscale(factor, mscale_all_dim)**2``
+    under YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    scale = (s.nope_dim + s.rope_dim) ** -0.5
+    if s.yarn is not None and s.yarn.mscale_all_dim:
+        scale *= yarn_mscale(s.yarn.factor, s.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_absorbed(p: Params, s: MLASpec, q_nope, q_rope, ckv, krope, *,
+                  q_pos, kv_valid):
+    """Latent attention without expanding the latent: q_nope (B,S,H,n),
+    q_rope (B,S,H,r), ckv (B,T,rank), krope (B,T,r) -> (B,S,H*v).
+
+    ``wkv_b`` splits per head into a key half W_k (rank, n) and a value
+    half W_v (rank, v).  Since q_nope . (c W_k) = (q_nope W_k^T) . c, the
+    query goes through W_k once and scores the T latent rows directly;
+    the probabilities combine the latent rows and W_v applies once to the
+    result.  Scores and softmax are whole-row, as on the naive path, so
+    every ``softmax_impl`` applies verbatim; the latent is read once per
+    tick instead of expanding to H*(n+v) values per position."""
+    b, sl, h = q_nope.shape[:3]
+    f32 = jnp.float32
+    w = p["wkv_b"]["w"].reshape(s.kv_lora_rank, h, s.nope_dim + s.v_dim)
+    w_k, w_v = w[..., :s.nope_dim], w[..., s.nope_dim:]
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_k,
+                       preferred_element_type=f32).astype(ckv.dtype)
+    scores = (jnp.einsum("bshr,btr->bsht", q_lat, ckv,
+                         preferred_element_type=f32)
+              + jnp.einsum("bshr,btr->bsht", q_rope.astype(krope.dtype),
+                           krope, preferred_element_type=f32))
+    scores = scores * mla_softmax_scale(s)
+    t_pos = jnp.arange(ckv.shape[1])[None, None, :]
+    mask = kv_valid[:, None, :] & (t_pos <= q_pos[:, :, None])  # (B,S,T)
+    scores = jnp.where(mask[:, :, None], scores, dp.MASK_VALUE)
+    probs = dispatch.get_softmax(s.softmax_impl)(scores).astype(ckv.dtype)
+    o_lat = jnp.einsum("bsht,btr->bshr", probs, ckv,
+                       preferred_element_type=f32).astype(ckv.dtype)
+    o = jnp.einsum("bshr,rhv->bshv", o_lat, w_v,
+                   preferred_element_type=f32)
+    return o.reshape(b, sl, h * s.v_dim)
+
+
 def mla_apply(p: Params, s: MLASpec, x, *, positions, cache=None, pos=0,
               paged=None, layer=0):
+    """Latent attention: the cache holds the normed latent and one shared
+    rope key per position.  Prefill expands the latent through ``wkv_b``
+    (under the named scope ``mla.expand``) and attends through the shared
+    core (``mla.attend``); a decode tick (one query a row, with a cache)
+    attends against the latent in the absorbed form
+    (:func:`_mla_absorbed`, ``mla.attend``)."""
     b, sl, _ = x.shape
     qk_head = s.nope_dim + s.rope_dim
     if s.q_lora_rank:
@@ -362,17 +413,17 @@ def mla_apply(p: Params, s: MLASpec, x, *, positions, cache=None, pos=0,
         q = linear(p["wq"], x)
     q = q.reshape(b, sl, s.n_heads, qk_head)
     q_nope, q_rope = q[..., : s.nope_dim], q[..., s.nope_dim:]
-    q_rope = apply_rope(q_rope, positions, s.rope_theta)
+    q_rope = apply_rope(q_rope, positions, s.rope_theta, s.yarn)
 
     kv_a = linear(p["wkv_a"], x)                       # (B,S,kv_lora+rope)
     ckv = rmsnorm(p["kv_norm"], kv_a[..., : s.kv_lora_rank], s.norm_eps)
     k_rope_new = apply_rope(kv_a[..., s.kv_lora_rank:][:, :, None, :],
-                            positions, s.rope_theta)[:, :, 0, :]
+                            positions, s.rope_theta, s.yarn)[:, :, 0, :]
 
     if paged is not None:
-        # MLA pages the COMPRESSED latent + rope key; the latent must
-        # expand densely before attention regardless, so the paged win is
-        # pure storage — gather once, then the dense path is unchanged.
+        # MLA pages the COMPRESSED latent + rope key: this layer's blocks
+        # of the rows' tables are gathered dense once, then attention
+        # runs as on the contiguous cache.
         cache = {"ckv": paged_write(cache["ckv"], ckv, pos, paged, layer),
                  "krope": paged_write(cache["krope"], k_rope_new, pos,
                                       paged, layer)}
@@ -391,23 +442,33 @@ def mla_apply(p: Params, s: MLASpec, x, *, positions, cache=None, pos=0,
         t = sl
         kv_valid = jnp.ones((b, sl), dtype=bool)
 
-    # expand latent -> per-head k_nope / v (naive MLA; absorbed form is a
-    # perf option, see EXPERIMENTS.md §Perf)
-    kv = linear(p["wkv_b"], ckv_all).reshape(b, t, s.n_heads,
-                                             s.nope_dim + s.v_dim)
-    k_nope, v = kv[..., : s.nope_dim], kv[..., s.nope_dim:]
+    if sl == 1 and cache is not None:
+        # a decode tick: attend against the latent itself (ROADMAP A5)
+        with jax.named_scope("mla.attend"):
+            o = _mla_absorbed(p, s, q_nope, q_rope, ckv_all, krope_all,
+                              q_pos=positions, kv_valid=kv_valid)
+        return linear(p["wo"], o.astype(x.dtype)), cache
+
+    # prefill: expand latent -> per-head k_nope / v over every cached
+    # position, then the shared attention core
+    with jax.named_scope("mla.expand"):
+        kv = linear(p["wkv_b"], ckv_all).reshape(b, t, s.n_heads,
+                                                 s.nope_dim + s.v_dim)
+        k_nope, v = kv[..., : s.nope_dim], kv[..., s.nope_dim:]
 
     # route through the shared core: concat rope/nope halves so MLA uses
     # the same naive/flash dispatch as GQA (K=n_heads, G=1)
-    q_cat = jnp.concatenate([q_nope, q_rope], axis=-1) \
-        .reshape(b, sl, s.n_heads, 1, qk_head)
-    k_cat = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(krope_all[:, :, None, :],
-                                  (b, t, s.n_heads, s.rope_dim))], axis=-1)
-    o = _sdpa(q_cat, k_cat, v, q_pos=positions, kv_valid=kv_valid,
-              softmax_impl=s.softmax_impl, causal=True,
-              scale=1.0 / qk_head ** 0.5, attn_impl=s.attn_impl,
-              ring_axis=s.ring_axis)
+    with jax.named_scope("mla.attend"):
+        q_cat = jnp.concatenate([q_nope, q_rope], axis=-1) \
+            .reshape(b, sl, s.n_heads, 1, qk_head)
+        k_cat = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(krope_all[:, :, None, :],
+                                      (b, t, s.n_heads, s.rope_dim))],
+            axis=-1)
+        o = _sdpa(q_cat, k_cat, v, q_pos=positions, kv_valid=kv_valid,
+                  softmax_impl=s.softmax_impl, causal=True,
+                  scale=mla_softmax_scale(s), attn_impl=s.attn_impl,
+                  ring_axis=s.ring_axis)
     o = o.reshape(b, sl, s.n_heads * s.v_dim)
     return linear(p["wo"], o), cache
 

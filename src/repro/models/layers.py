@@ -88,13 +88,48 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (..., S, H, hd) rotate-half RoPE; positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, ``0.1 mscale ln(factor) + 1``
+    (1 when the positions are not stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """DeepSeek-V2's YaRN frequencies (``DeepseekV2YarnRotaryEmbedding``):
+    ``1/theta^(2i/d)`` kept where a dimension turns more than
+    ``beta_fast`` times in ``original_max_pos`` positions, divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, blended by
+    a linear ramp in between.  ``yarn`` is a ``configs.base.YarnCfg``."""
+    def dim_of(rotations):
+        return (head_dim * math.log(yarn.original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    keep = 1.0 - ramp
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x, positions, theta: float = 10000.0, yarn=None):
+    """x: (..., S, H, hd) rotate-half RoPE; positions: (..., S).  With
+    ``yarn`` (a ``configs.base.YarnCfg``) the frequencies are YaRN's and
+    cos/sin carry ``mscale(mscale) / mscale(mscale_all_dim)``."""
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)                                   # (hd/2,)
+    inv = (rope_freqs(hd, theta) if yarn is None
+           else yarn_freqs(hd, theta, yarn))                      # (hd/2,)
     ang = positions[..., :, None].astype(jnp.float32) * inv       # (..,S,hd/2)
     cos = jnp.cos(ang)[..., None, :]                              # (..,S,1,hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -1,6 +1,27 @@
-"""Mixture-of-Experts: token-choice top-k routing with two dispatch paths.
+"""Mixture-of-Experts: token-choice top-k routing, one layer that may hold
+only a share of the experts, and three dispatch paths.
 
-  'sort'  — production path, GROUP-LOCAL: every sequence routes its own S
+Routing scores every one of the ``n_experts`` router outputs (softmax,
+greedy top-k), renormalises the k gates only if ``norm_topk_prob`` and
+scales them by ``routed_scale``; with ``router_f32`` the router's product
+and the gates are float32, as DeepSeek-V2's gate computes them.  The layer
+holds experts ``[first_held, first_held + n_held)`` of them (all by
+default): one chip's share under expert parallelism.  An expert it does
+not hold adds nothing, so the layer's output is that share's part of the
+result, plus the shared experts, which every share computes.
+
+  serving ('dropless', the engine's prefill and decode) — the routed
+            rows that land on held experts, sorted by expert and run
+            through ``jax.lax.ragged_dot``: no capacity, so no token is
+            dropped at any chunk length and a token's output never
+            depends on what else shares its chunk.  Only held experts'
+            rows are computed.  Under a mesh (``axes`` given) serving
+            keeps the group-local 'sort' dispatch below with its pins:
+            dropless up to ``dropless_max_seq`` tokens a sequence, then
+            bounded at ``inference_cf`` x the balanced load (a flattened
+            sort there would gather the global token set); a held share
+            is served on one chip only.
+  'sort'  — training, GROUP-LOCAL: every sequence routes its own S
             tokens (sort by expert id within the sequence, scatter into a
             per-sequence (E, C_g, d) capacity buffer, batched expert FFN,
             gather back).  Because the group axis is the batch axis, the
@@ -11,13 +32,15 @@
             per step at 256 chips — group-local dispatch removes it.)
             Capacity is per group: C_g = ceil(S*k/E * cf), the per-batch
             balance modern MoE trainers use.
-  'dense' — reference path: compute every expert for every token, weight by
-            gates.  Exact (no capacity drops); used by tests as the oracle
-            and by tiny smoke configs.
+  'dense' — reference path: compute every held expert for every token,
+            weight by gates.  Exact (no capacity drops); used by tests as
+            the oracle and by tiny smoke configs.
 
 Includes shared experts (DeepSeek-V2) and the standard load-balance aux
 loss.  Expert FFNs use the configured activation, so the paper's dual-mode
-unit serves MoE experts too.
+unit serves MoE experts too.  The router, the held experts and the shared
+experts run under the named scopes ``moe.route``, ``moe.experts`` and
+``moe.shared``.
 """
 from __future__ import annotations
 
@@ -53,19 +76,27 @@ class MoESpec(NamedTuple):
     ffn_impl: str = "dense"   # shared-expert MLP execution (dispatch registry)
     dispatch: str = "sort"    # 'sort' | 'dense'
     ep_pad: int = 0           # padded stack size (0 = n_experts)
-    # inference capacity: truly dropless (cap=S) is exact for short
-    # sequences (decode, engine tests) but at 32k-token prefill the
-    # worst-case buffer is S/E-fold oversized (hundreds of TB) — beyond
-    # this length we bound capacity at inference_cf x the balanced load,
-    # the standard serving trade-off.
+    # mesh serving's capacity: truly dropless (cap=S) is exact for short
+    # sequences but at 32k-token prefill the worst-case buffer is S/E-fold
+    # oversized (hundreds of TB) — beyond this length capacity is bounded
+    # at inference_cf x the balanced load.  One chip serves dropless.
     dropless_max_seq: int = 1024
     inference_cf: float = 2.0
+    norm_topk_prob: bool = True
+    routed_scale: float = 1.0
+    router_f32: bool = False  # float32 router product and gates
+    first_held: int = 0       # held experts [first_held, +n_held)
+    n_held: int = 0           # 0 = all n_experts
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 def moe_init(key, s: MoESpec, dtype) -> Params:
     kr, ke, ks = jax.random.split(key, 3)
     kg, ku, kd = jax.random.split(ke, 3)
-    e = max(s.ep_pad, s.n_experts)       # padded experts are dead weight
+    e = max(s.ep_pad, s.held)            # padded experts are dead weight
     p = {
         "router": dense_init(kr, s.d_model, s.n_experts, dtype,
                              scale=0.02),
@@ -87,13 +118,23 @@ def _stack_init(key, e: int, d_in: int, d_out: int, dtype):
 def _route(p: Params, s: MoESpec, x):
     """(B,S,d) -> gates (B,S,k), expert idx (B,S,k), aux loss.
 
-    Routing stays in batch-major layout end to end — a flattened (T,E)
-    router forces GSPMD to all-gather the global token set for top_k
-    (measured 10.7 GB/step at granite train_4k)."""
-    logits = (x @ p["router"]).astype(jnp.float32)           # (B,S,E)
+    With ``router_f32`` the router's product and the gates are float32
+    (DeepSeek-V2's gate); otherwise the product is in the activation
+    dtype and the gates come back in it.  Routing stays in batch-major
+    layout end to end — a flattened (T,E) router forces GSPMD to
+    all-gather the global token set for top_k (measured 10.7 GB/step at
+    granite train_4k)."""
+    if s.router_f32:
+        logits = jnp.dot(x, p["router"],
+                         preferred_element_type=jnp.float32)  # (B,S,E)
+    else:
+        logits = (x @ p["router"]).astype(jnp.float32)       # (B,S,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, s.top_k)               # (B,S,k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if s.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if s.routed_scale != 1.0:
+        gates = gates * s.routed_scale
     # aux loss: E * sum_e f_e * p_e   (Switch Transformer eq. 4); counts
     # via one-hot sums (shard-local), not a global scatter
     me = jnp.mean(probs, axis=(0, 1))                        # (E,)
@@ -101,7 +142,7 @@ def _route(p: Params, s: MoESpec, x):
                  axis=(0, 1, 2))
     ce = ce / (x.shape[0] * x.shape[1] * s.top_k)
     aux = s.n_experts * jnp.sum(me * ce)
-    return gates.astype(x.dtype), idx, aux
+    return gates.astype(jnp.float32 if s.router_f32 else x.dtype), idx, aux
 
 
 def _expert_ffn(p: Params, s: MoESpec, xb):
@@ -205,6 +246,10 @@ def _moe_sort(p: Params, s: MoESpec, x, gates, idx, dropless=False,
               axes=None):
     """Group-local dispatch over the batch axis.  x (B,S,d) -> (B,S,d).
 
+    ``dropless`` (mesh serving): capacity S up to ``dropless_max_seq``,
+    so nothing is dropped; longer sequences are bounded at
+    ``inference_cf``.
+
     `axes` = (dp_axis, ep_axis) mesh-axis names: explicit sharding pins on
     the dispatch buffers — GSPMD loses the batch sharding through the
     batched scatter otherwise (measured: full-B f32 buffers replicated on
@@ -215,8 +260,7 @@ def _moe_sort(p: Params, s: MoESpec, x, gates, idx, dropless=False,
         cap = sl       # an expert can receive at most S slots: zero drops
     else:
         cf = s.inference_cf if dropless else s.capacity_factor
-        cap = int(math.ceil(sl * k / s.n_experts * cf))
-        cap = min(cap, sl)
+        cap = min(int(math.ceil(sl * k / s.n_experts * cf)), sl)
 
     e_buf = max(s.ep_pad, s.n_experts)
     # Two dispatch layouts (chosen at trace time from shapes + mesh):
@@ -270,31 +314,97 @@ def _moe_sort(p: Params, s: MoESpec, x, gates, idx, dropless=False,
     return jax.vmap(gather_back)(h, meta, idx)
 
 
+def _held_slot(s: MoESpec, idx):
+    """Each routed slot's expert in the held stack, and whether it is held
+    at all; an absent expert's slot points one past the stack."""
+    local = idx - s.first_held
+    held = (local >= 0) & (local < s.held)
+    return jnp.where(held, local, max(s.ep_pad, s.held)), held
+
+
 def _moe_dense(p: Params, s: MoESpec, x_flat, gates, idx):
-    # (T,d) through every expert: (E,T,d); weight by scattered gates
+    # (T,d) through every held expert: (E,T,d); weight by scattered gates
     act = get_activation(s.activation)
     g = jnp.einsum("td,edf->etf", x_flat, p["gate"])
     u = jnp.einsum("td,edf->etf", x_flat, p["up"])
     h = jnp.einsum("etf,efd->etd", act(g) * u, p["down"])     # (E,T,d)
-    w = jnp.zeros((x_flat.shape[0], p["gate"].shape[0]), x_flat.dtype)
-    w = jax.vmap(lambda wi, ii, gi: wi.at[ii].add(gi))(w, idx, gates)
+    w = jnp.zeros((x_flat.shape[0], p["gate"].shape[0]), gates.dtype)
+    slot, _ = _held_slot(s, idx)
+    w = jax.vmap(lambda wi, ii, gi: wi.at[ii].add(gi, mode="drop"))(
+        w, slot, gates)
     return jnp.einsum("etd,te->td", h, w)
 
 
-def moe_apply(p: Params, s: MoESpec, x, dropless: bool = False, axes=None):
-    """x: (B,S,d) -> (y, aux_loss).
+def _moe_held(p: Params, s: MoESpec, x_flat, gates, idx):
+    """The serving dispatch: x_flat (T,d), gates/idx (T,k) -> (y (T,d),
+    routed rows that landed on held experts).
 
-    dropless=True (inference): no token drops up to `dropless_max_seq`
-    (capacity-bounded routing is a *training* throughput device and would
-    make decode outputs depend on what else shares the batch); longer
-    prefills fall back to inference_cf-bounded capacity."""
+    The T*k routed slots are sorted by held expert (absent ones last) and
+    the held groups run through ``ragged_dot``; no capacity, so nothing
+    is dropped.  The buffer is T*k rows, the most a held share can be
+    routed, and only the leading groups are computed."""
+    t, d = x_flat.shape
+    slot, held = _held_slot(s, idx.reshape(-1))
+    order = jnp.argsort(slot, stable=True)
+    sizes = jnp.zeros((p["gate"].shape[0],), jnp.int32).at[slot].add(
+        1, mode="drop")
+    rows = x_flat[order // s.top_k]                         # (T*k, d)
+    act = get_activation(s.activation)
+    g = jax.lax.ragged_dot(rows, p["gate"], sizes)
+    u = jax.lax.ragged_dot(rows, p["up"], sizes)
+    h = jax.lax.ragged_dot(act(g) * u, p["down"], sizes)
+    # back to token-major slots; rows past the held groups are not
+    # computed and are selected away, never multiplied
+    h = jnp.zeros_like(h).at[order].set(h, unique_indices=True)
+    h = jnp.where(held[:, None], h, 0).reshape(t, s.top_k, d)
+    return (jnp.sum(h * gates[..., None], axis=1),
+            jnp.sum(held, dtype=jnp.float32))
+
+
+def moe_apply(p: Params, s: MoESpec, x, dropless: bool = False, axes=None):
+    """x: (B,S,d) -> (y, aux).
+
+    Training (``dropless=False``): capacity-bounded 'sort' dispatch (or
+    the 'dense' oracle); ``aux`` is the load-balance loss.  A held share
+    is served only: training holds every expert.
+
+    Serving (``dropless=True``): the held experts' routed rows through
+    ``ragged_dot`` (or the 'dense' oracle), with no capacity — dropping
+    would make a token's output depend on what else shares the batch;
+    ``aux`` is the number of routed rows that landed on held experts, a
+    float32 count reduced on the device.  Under a mesh (``axes``) serving
+    runs the pinned group-local 'sort' dispatch (module docstring) and
+    holds every expert."""
     b, sl, d = x.shape
-    gates, idx, aux = _route(p, s, x)
-    if s.dispatch == "dense":
-        y = _moe_dense(p, s, x.reshape(-1, d), gates.reshape(-1, s.top_k),
-                       idx.reshape(-1, s.top_k)).reshape(b, sl, d)
+    with jax.named_scope("moe.route"):
+        gates, idx, aux = _route(p, s, x)
+    x_flat = x.reshape(-1, d)
+    g_flat, i_flat = gates.reshape(-1, s.top_k), idx.reshape(-1, s.top_k)
+    if s.held != s.n_experts and (axes is not None or not dropless):
+        raise ValueError("an expert share (n_held < n_experts) is served "
+                         "on one chip only; training and mesh serving hold "
+                         "every expert")
+    if dropless and axes is not None and s.dispatch != "dense":
+        with jax.named_scope("moe.experts"):
+            y = _moe_sort(p, s, x, gates.astype(x.dtype), idx,
+                          dropless=True, axes=axes)
+        aux = jnp.float32(b * sl * s.top_k)
+    elif dropless:
+        with jax.named_scope("moe.experts"):
+            if s.dispatch == "dense":
+                y = _moe_dense(p, s, x_flat, g_flat, i_flat)
+                aux = jnp.sum(_held_slot(s, i_flat)[1], dtype=jnp.float32)
+            else:
+                y, aux = _moe_held(p, s, x_flat, g_flat, i_flat)
+        y = y.astype(x.dtype).reshape(b, sl, d)
+    elif s.dispatch == "dense":
+        with jax.named_scope("moe.experts"):
+            y = _moe_dense(p, s, x_flat, g_flat.astype(x.dtype),
+                           i_flat).reshape(b, sl, d)
     else:
-        y = _moe_sort(p, s, x, gates, idx, dropless=dropless, axes=axes)
+        with jax.named_scope("moe.experts"):
+            y = _moe_sort(p, s, x, gates.astype(x.dtype), idx, axes=axes)
     if s.n_shared:
-        y = y + mlp(p["shared"], x, s.activation, impl=s.ffn_impl)
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(p["shared"], x, s.activation, impl=s.ffn_impl)
     return y, aux

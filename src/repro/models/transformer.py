@@ -49,7 +49,7 @@ def mla_spec(cfg: ModelConfig) -> MLASpec:
                    m.nope_dim, m.rope_dim, m.v_dim,
                    rope_theta=cfg.rope_theta, softmax_impl=cfg.softmax_impl,
                    attn_impl=cfg.attn_impl, ring_axis=cfg.ring_axis,
-                   norm_eps=cfg.norm_eps)
+                   norm_eps=cfg.norm_eps, yarn=cfg.rope_yarn)
 
 
 def mamba_spec(cfg: ModelConfig) -> MambaSpec:
@@ -65,7 +65,10 @@ def moe_spec(cfg: ModelConfig) -> MoESpec:
     m = cfg.moe
     return MoESpec(cfg.d_model, m.d_ff, m.n_experts, m.top_k, m.n_shared,
                    m.capacity_factor, cfg.activation, cfg.ffn_impl,
-                   cfg.moe_dispatch, ep_pad=m.ep_pad)
+                   cfg.moe_dispatch, ep_pad=m.ep_pad,
+                   norm_topk_prob=m.norm_topk_prob,
+                   routed_scale=m.routed_scale, router_f32=m.router_f32,
+                   first_held=m.first_held, n_held=m.n_held)
 
 
 # ---------------- block ----------------
@@ -148,6 +151,9 @@ def _pin(ctx: Ctx, x, kind: str):
 
 def block_apply(p: Params, cfg: ModelConfig, spec: LayerSpec, x, cache,
                 ctx: Ctx):
+    """-> (x, new_cache, aux); aux is the MoE layer's (``moe_apply``):
+    the load-balance loss in train mode, the rows its held experts
+    computed when ``ctx.cached``; 0.0 for any other layer."""
     _, norm = make_norm(cfg.norm)
     new_cache: Params = {}
     aux = jnp.zeros((), jnp.float32)
@@ -412,6 +418,11 @@ def lm_apply(params: Params, cfg: ModelConfig, tokens, *, pos=0,
              remat_mode: str = "period", paged=None):
     """tokens (B,S) -> (logits, new_caches, aux).
 
+    aux          : summed over the MoE layers (0.0 without any): their
+                   load-balance loss in train mode; with caches (serving,
+                   where there is no loss) the routed rows that landed on
+                   the layers' held experts, a float32 count
+
     caches=None  : train mode (full forward, no state threading)
     caches given : prefill (pos=0, S=seq) or decode (S=1, pos=offset)
     remat        : activation-checkpoint each scan period (train mode) —
@@ -488,7 +499,8 @@ def lm_apply(params: Params, cfg: ModelConfig, tokens, *, pos=0,
             for j, spec in enumerate(cfg.pattern):
                 x, nc, aux = block_apply(pp[j], cfg, spec, x, pools[j], lctx)
                 new.append(nc)
-            return (pin(x), aux_acc + aux, new), None
+                aux_acc = aux_acc + aux
+            return (pin(x), aux_acc, new), None
 
         (x, aux_total, pools), _ = jax.lax.scan(
             body, (x, aux_total, caches["periods"]),
@@ -503,7 +515,8 @@ def lm_apply(params: Params, cfg: ModelConfig, tokens, *, pos=0,
                 bp = jax.tree.map(lambda a: a, pp[j])
                 x, nc, aux = block_apply(bp, cfg, spec, x, pc[j], ctx)
                 ncs.append(nc)
-            return (pin(x), aux_acc + aux), ncs
+                aux_acc = aux_acc + aux
+            return (pin(x), aux_acc), ncs
 
         (x, aux_total), period_caches = jax.lax.scan(
             body, (x, aux_total), (params["periods"], caches["periods"]))

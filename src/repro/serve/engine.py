@@ -111,10 +111,13 @@ def make_decode_step(cfg: ModelConfig, act_pspec=None):
     return decode
 
 
-def make_chunk_prefill_step(cfg: ModelConfig, act_pspec=None):
+def make_chunk_prefill_step(cfg: ModelConfig, act_pspec=None,
+                            counts: bool = False):
     """(params, caches, tokens(1,C), pos, tables(1,max_blocks),
     last_idx(1,)) -> (logits(1,V), caches) — ONE prompt chunk written
-    through the slot's block table at traced offset ``pos``.
+    through the slot's block table at traced offset ``pos``.  With
+    ``counts`` a third output is the rows the MoE layers' held experts
+    computed (a float32 scalar, see ``lm_apply``'s aux).
 
     One compiled shape serves every chunk of every prompt: position is a
     traced scalar, the table a traced operand.  ``last_idx`` picks the
@@ -125,14 +128,16 @@ def make_chunk_prefill_step(cfg: ModelConfig, act_pspec=None):
     buffers become the returned caches, and only the chunk's rows are
     written."""
     def prefill_chunk(params, caches, tokens, pos, tables, last_idx):
-        logits, caches, _ = lm_apply(params, cfg, tokens, pos=pos,
-                                     caches=caches, last_pos=last_idx,
-                                     act_pspec=act_pspec, paged=tables)
-        return logits[:, -1, :], caches
+        logits, caches, held = lm_apply(params, cfg, tokens, pos=pos,
+                                        caches=caches, last_pos=last_idx,
+                                        act_pspec=act_pspec, paged=tables)
+        out = (logits[:, -1, :], caches)
+        return out + (held,) if counts else out
     return prefill_chunk
 
 
-def make_paged_decode_step(cfg: ModelConfig, act_pspec=None):
+def make_paged_decode_step(cfg: ModelConfig, act_pspec=None,
+                           counts: bool = False):
     """(params, caches, tokens(B,1), pos(B,), tables(B,max_blocks)) ->
     (logits(B,V), caches) — the lockstep decode tick reading/writing
     K/V through per-slot block tables.  Rows that must not write (free
@@ -140,12 +145,15 @@ def make_paged_decode_step(cfg: ModelConfig, act_pspec=None):
     their scatter lands in block 0 and touches nothing live.
     ``ServeEngine`` jits it with the caches donated: the caches passed in
     are consumed and updated in place (B new rows a layer), and the
-    kernel reads the stacked pools at the scan's layer index."""
+    kernel reads the stacked pools at the scan's layer index.  With
+    ``counts``, the held experts' rows as a third output, as for
+    :func:`make_chunk_prefill_step`."""
     def decode(params, caches, tokens, pos, tables):
-        logits, caches, _ = lm_apply(params, cfg, tokens, pos=pos,
-                                     caches=caches, act_pspec=act_pspec,
-                                     paged=tables)
-        return logits[:, -1, :], caches
+        logits, caches, held = lm_apply(params, cfg, tokens, pos=pos,
+                                        caches=caches, act_pspec=act_pspec,
+                                        paged=tables)
+        out = (logits[:, -1, :], caches)
+        return out + (held,) if counts else out
     return decode
 
 
@@ -382,13 +390,27 @@ class ServeEngine:
                                   softmax_impl=self.prefill_softmax_impl)
         decode_cfg = cfg.replace(attn_impl=self.decode_attn_impl,
                                  softmax_impl=self.decode_softmax_impl)
+        # counters of the paged path's MoE and MLA work (none for a model
+        # without either): routed rows per token over all MoE layers, and
+        # the held experts' share of them, counted on the device
+        specs = tuple(cfg.prefix) + tuple(cfg.pattern) * cfg.n_periods
+        moe_layers = sum(sp.ffn == "moe" for sp in specs)
+        self._routed_per_token = (cfg.moe.top_k * moe_layers
+                                  if moe_layers and self.cache_mode == "paged"
+                                  else 0)
+        self._mla = (self.cache_mode == "paged"
+                     and any(sp.mixer == "mla" for sp in specs))
+        self._held: list = []          # device counts not pulled yet
         if self.cache_mode == "paged":
             # the pools are donated: each step updates them in place and
             # the engine rebinds self.caches to the result
-            self._prefill = jax.jit(make_chunk_prefill_step(prefill_cfg),
-                                    donate_argnums=(1,))
-            self._decode = jax.jit(make_paged_decode_step(decode_cfg),
-                                   donate_argnums=(1,))
+            counts = bool(self._routed_per_token)
+            self._prefill = jax.jit(
+                make_chunk_prefill_step(prefill_cfg, counts=counts),
+                donate_argnums=(1,))
+            self._decode = jax.jit(
+                make_paged_decode_step(decode_cfg, counts=counts),
+                donate_argnums=(1,))
         else:
             self._prefill = jax.jit(make_prefill_step(prefill_cfg))
             self._decode = jax.jit(make_decode_step(decode_cfg))
@@ -407,10 +429,37 @@ class ServeEngine:
                       "resumes": 0, "hol_skips": 0, "admit_blocked": 0,
                       "numeric": 0, "corrupt": 0, "deadlines": 0,
                       "starved": []}
+        if self._routed_per_token:
+            # summed over MoE layers and steps; held rows as of the last
+            # host pull
+            self.stats.update(moe_routed_rows=0, moe_held_rows=0.0)
+        if self._mla:
+            # per decode tick: latent positions the attention reads (the
+            # whole table of every slot, from the program's shapes) and
+            # the positions live in the decoding slots
+            self.stats.update(mla_latent_read=0, decode_kv_live=0)
 
     def _mesh_ctx(self):
         return jax.set_mesh(self.mesh) if self.mesh is not None else (
             contextlib.nullcontext())
+
+    def _step_out(self, out, tokens: int):
+        """(logits, caches) of a paged step program; a counting program's
+        held-row count is kept on the device until the next pull."""
+        if self._routed_per_token:
+            self._held.append(out[2])
+            self.stats["moe_routed_rows"] += tokens * self._routed_per_token
+        return out[0], out[1]
+
+    def _pull(self, x) -> np.ndarray:
+        """``x`` on the host; held-row counts dispatched since the last
+        pull come back in the same transfer, with no sync of their own."""
+        if not self._held:
+            return np.asarray(x)
+        x, held = jax.device_get((x, self._held))
+        self.stats["moe_held_rows"] += float(sum(held))
+        self._held = []
+        return np.asarray(x)
 
     # ---- host-side bookkeeping ----
 
@@ -794,16 +843,16 @@ class ServeEngine:
             last_idx = jnp.asarray([len(real) - 1], jnp.int32)
             tables = jnp.asarray(self._tables[i:i + 1])
             with self._mesh_ctx():
-                logits, self.caches = self._prefill(
+                logits, self.caches = self._step_out(self._prefill(
                     self.params, self.caches, toks, jnp.int32(c0), tables,
-                    last_idx)
+                    last_idx), self.prefill_chunk)
             s.filled = c0 + len(real)
             self.stats["prefill_chunks"] += 1
             if s.filled >= len(s.prompt):
                 if self.faults is not None:
                     logits = self.faults.prefill_logits(
                         self.stats["engine_steps"], s.rid, logits)
-                if not bool(np.asarray(jnp.isfinite(logits).all())):
+                if not bool(self._pull(jnp.isfinite(logits).all())):
                     self.stats["numeric"] += 1
                     self._finish_slot(i, "numeric")
                     return
@@ -900,9 +949,14 @@ class ServeEngine:
                     # slot's blocks
                     masked = np.where(np.asarray(decoding)[:, None],
                                       self._tables, 0)
-                    logits, self.caches = self._decode(
+                    logits, self.caches = self._step_out(self._decode(
                         self.params, self.caches, self._last_tok, pos,
-                        jnp.asarray(masked))
+                        jnp.asarray(masked)), self.n_slots)
+                    if self._mla:
+                        self.stats["mla_latent_read"] += (
+                            self.n_slots * self.max_blocks * self.block_size)
+                        self.stats["decode_kv_live"] += sum(
+                            s.pos + 1 for s in self._slots if s.decoding)
                 else:
                     logits, self.caches = self._decode(
                         self.params, self.caches, self._last_tok, pos)
@@ -916,7 +970,7 @@ class ServeEngine:
             # refunded); the per-slot sampling keys below are split from
             # the step key by slot INDEX, so the neighbours' token streams
             # are bitwise unaffected by the quarantine.
-            finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
+            finite = self._pull(jnp.isfinite(logits).all(axis=-1))
         with jax.profiler.TraceAnnotation("engine.sample"):
             self.stats["decode_steps"] += 1
             self._key, k = jax.random.split(self._key)

@@ -125,6 +125,21 @@ def test_full_configs_match_assignment():
     c = registry.get_config("deepseek-v2-lite-16b")
     assert c.mla.kv_lora_rank == 512 and c.moe.top_k == 6
     assert c.moe.n_shared == 2
+    # hf:deepseek-ai/DeepSeek-V2-Lite config.json
+    assert (c.n_layers, c.d_model, c.n_heads, c.d_ff, c.vocab) == (
+        27, 2048, 16, 10944, 102400)
+    assert (c.mla.q_lora_rank, c.mla.nope_dim, c.mla.rope_dim,
+            c.mla.v_dim) == (0, 128, 64, 128)
+    assert (c.moe.n_experts, c.moe.d_ff, c.moe.norm_topk_prob,
+            c.moe.routed_scale) == (64, 1408, False, 1.0)
+    assert (c.moe.first_held, c.moe.n_held) == (0, 0)     # all held
+    assert len(c.prefix) == 1 and c.prefix[0].ffn == "mlp"
+    assert c.pattern[0].ffn == "moe" and c.rope_theta == 10000.0
+    y = c.rope_yarn
+    assert (y.factor, y.original_max_pos, y.beta_fast, y.beta_slow,
+            y.mscale, y.mscale_all_dim) == (40.0, 4096, 32.0, 1.0, 0.707,
+                                            0.707)
+    assert not c.tie_embeddings and c.norm_eps == 1e-6
     c = registry.get_config("minicpm3-4b")
     assert c.n_layers == 62 and c.mla is not None
     c = registry.get_config("rwkv6-1.6b")

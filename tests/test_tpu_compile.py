@@ -15,7 +15,10 @@ one process may load the TPU library at a time, and every test worker
 imports this file.  All chip descriptions live in this one file.
 
 The serve engine's paged step programs are compiled whole as well, with
-their caches donated, to pin that a step keeps the KV pool in place.
+their caches donated, to pin that a step keeps the KV pool in place; and
+DeepSeek-V2-Lite's at its benchmark cell's size (27 layers, 8 held
+experts, 16 slots of 8192 tokens), to pin that they fit the chip and that
+every kernel its dispatch picks compiles at MLA's head dims.
 """
 from __future__ import annotations
 
@@ -299,3 +302,83 @@ def test_paged_step_keeps_the_pool_in_place(spec, monkeypatch, phase,
         a.size * a.dtype.itemsize for a in pools)
     sizes = {a.size for a in pools} | {a.size // a.shape[0] for a in pools}
     assert _pool_moves(hlo, sizes) == []
+
+
+# ---------------- deepseek-v2-lite at its cell's size ----------------
+
+# the bench cell deepseek-v2-lite.doc_chat: all 27 layers at published
+# widths, 8 of 64 experts held per MoE layer, 16 slots of 8192 tokens,
+# 1025 blocks of 128 tokens, prefill chunks of 2048
+DS_SLOTS, DS_BLOCKS, DS_BS, DS_SEQ, DS_CHUNK = 16, 1025, 128, 8192, 2048
+HBM_BYTES = int(15.75 * 2 ** 30)       # a v5e's usable device memory
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_deepseek_step_programs_fit_and_compile(spec, monkeypatch, phase):
+    """DeepSeek-V2-Lite's paged step programs as ``ServeEngine`` builds
+    them on the chip (the impls its dispatch resolves there, caches
+    donated, held-row counts on): the pools are aliased to the output,
+    the compiled arguments plus temporaries fit the chip, and every
+    kernel the dispatch picked is in the program — the Pallas flash
+    kernel for the chunk's attention at q.k head dim 192 and v head dim
+    128, the fused GLU at the dense layer's 10944 and the shared experts'
+    2816, the fused residual norm, and the ragged expert matmuls.  The
+    decode tick attends against the latent in the absorbed form, with no
+    attention kernel and no expanded keys."""
+    import dataclasses
+
+    from repro.configs import registry
+    from repro.kernels import tiling
+    from repro.models import flash
+    from repro.models.transformer import init_lm, init_paged_caches
+    from repro.serve.engine import (make_chunk_prefill_step,
+                                    make_paged_decode_step)
+    monkeypatch.setattr(tiling, "interpret_mode", lambda: False)
+    cfg = registry.get_config("deepseek-v2-lite-16b")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, n_held=8),
+                      ffn_impl="fused_pallas", norm_impl="fused_pallas")
+    nblk = DS_SEQ // DS_BS
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda: init_lm(jax.random.PRNGKey(0), cfg, BF16)))
+    caches = shapes(jax.eval_shape(
+        lambda: init_paged_caches(cfg, DS_BLOCKS, DS_BS, BF16)))
+    if phase == "prefill":
+        # the auto rule as on the chip: a 2048 x 8192 score tile streams
+        # through the blocked kernel
+        impl = (flash.blocked_impl("tpu") if flash.use_flash(DS_CHUNK,
+                                                             DS_SEQ)
+                else "naive")
+        fn = make_chunk_prefill_step(cfg.replace(attn_impl=impl),
+                                     counts=True)
+        args = (params, caches, spec((1, DS_CHUNK), I32), spec((), I32),
+                spec((1, nblk), I32), spec((1,), I32))
+        assert impl == "flash_pallas"
+    else:
+        fn = make_paged_decode_step(cfg, counts=True)
+        args = (params, caches, spec((DS_SLOTS, 1), I32),
+                spec((DS_SLOTS,), I32), spec((DS_SLOTS, nblk), I32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert mem.alias_size_in_bytes == sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    hlo = compiled.as_text()
+    kernels = set(re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo))
+    wants = ["moe.shared/jit(_fused_glu_jit)", "_resnorm_jit", "ragged-dot"]
+    if phase == "prefill":
+        wants.append("_flash_pallas_jit")
+    else:
+        # no key is expanded: no buffer holds H x (nope + v) values for
+        # every position of the slots' tables
+        expanded = DS_SLOTS * DS_SEQ * cfg.n_heads * (
+            cfg.mla.nope_dim + cfg.mla.v_dim)
+        assert f"{DS_SLOTS},{DS_SEQ},{cfg.n_heads}," not in hlo
+        assert mem.temp_size_in_bytes < expanded * 2
+    for want in wants:
+        assert any(want in k for k in kernels), (want, sorted(kernels))
+    assert sum("_fused_glu_jit" in k for k in kernels) == 2
