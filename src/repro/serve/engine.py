@@ -181,11 +181,53 @@ def _put_blocks(caches, idx, saved):
         rows.astype(leaf.dtype)), caches, saved)
 
 
-def sample_token(key, logits, temperature: float):
-    greedy = jnp.argmax(logits, axis=-1)
-    if temperature <= 0.0:
-        return greedy
-    return jax.random.categorical(key, logits / temperature, axis=-1)
+@jax.jit
+def _sample_rows(keys, logits, temperature):
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    hot = temperature > 0
+    # jax.random.categorical written out, each stage stored in the
+    # logits' dtype as the per-row eager ops store it: fused, the TPU
+    # keeps bf16 stages in float32, which moves near-tied draws
+    rounded = jax.lax.optimization_barrier
+    scaled = rounded(logits / jnp.where(hot, temperature, 1)[:, None])
+    noise = rounded(jax.vmap(lambda k: jax.random.gumbel(
+        k, logits.shape[1:], logits.dtype))(keys))
+    drawn = jnp.argmax(rounded(scaled + noise), axis=-1)
+    return jnp.where(hot, drawn.astype(jnp.int32), greedy)
+
+
+def sample_token(keys, logits, temperature):
+    """keys (B, 2), logits (B, V), host temperatures (B,) -> tokens (B,)
+    int32, left on the device: one program for the whole batch.
+
+    A row at temperature <= 0 takes its argmax; any other row draws
+    ``jax.random.categorical(keys[i], logits[i] / t_i)``.  The
+    temperatures are cast to the logits' dtype on the host and divide in
+    it, as a Python float divisor would, so each row's token is bitwise
+    the one a call on that row alone gives."""
+    t = np.asarray(temperature, np.float64).astype(logits.dtype)
+    return _sample_rows(keys, logits, t)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _next_keys(key, n: int):
+    """(the engine key's successor, (max(n, 1), 2) row keys): a decode
+    tick splits its key into one per slot, by slot index; a prompt's
+    first token (n=0) takes it whole."""
+    key, k = jax.random.split(key)
+    return key, (k[None] if n == 0 else jax.random.split(k, n))
+
+
+@jax.jit
+def _finite_rows(logits):
+    return jnp.isfinite(logits).all(axis=-1)
+
+
+@jax.jit
+def _feed_back(last_tok, tokens, fed):
+    """``last_tok`` (B, 1) with the rows ``fed`` set to ``tokens``."""
+    return jnp.where(fed[:, None], tokens[:, None].astype(last_tok.dtype),
+                     last_tok)
 
 
 # ---------------- engine ----------------
@@ -428,7 +470,7 @@ class ServeEngine:
                       "preemptions": 0, "swap_outs": 0, "swap_ins": 0,
                       "resumes": 0, "hol_skips": 0, "admit_blocked": 0,
                       "numeric": 0, "corrupt": 0, "deadlines": 0,
-                      "starved": []}
+                      "sample_calls": 0, "starved": []}
         if self._routed_per_token:
             # summed over MoE layers and steps; held rows as of the last
             # host pull
@@ -451,15 +493,21 @@ class ServeEngine:
             self.stats["moe_routed_rows"] += tokens * self._routed_per_token
         return out[0], out[1]
 
-    def _pull(self, x) -> np.ndarray:
-        """``x`` on the host; held-row counts dispatched since the last
-        pull come back in the same transfer, with no sync of their own."""
-        if not self._held:
-            return np.asarray(x)
+    def _pull(self, x):
+        """``x`` (an array or a tuple of them) on the host, in one
+        transfer; held-row counts dispatched since the last pull come
+        back in it too, with no sync of their own."""
         x, held = jax.device_get((x, self._held))
-        self.stats["moe_held_rows"] += float(sum(held))
-        self._held = []
-        return np.asarray(x)
+        if held:
+            self.stats["moe_held_rows"] += float(sum(held))
+            self._held = []
+        return x
+
+    def _sample(self, keys, logits, temperatures: list[float]):
+        """One dispatch of the module's ``sample_token``, looked up at
+        call time so a wrapped sampler takes effect."""
+        self.stats["sample_calls"] += 1
+        return sample_token(keys, logits, temperatures)
 
     # ---- host-side bookkeeping ----
 
@@ -614,9 +662,10 @@ class ServeEngine:
                                full_prompt=list(req.prompt),
                                priority=req.priority,
                                deadline_at=entry.deadline_at)
-        self._key, k = jax.random.split(self._key)
-        first = sample_token(k, logits[0], req.temperature)
-        self._slots[i].out.append(int(first))
+        self._key, keys = _next_keys(self._key, 0)
+        first = int(self._pull(self._sample(keys, logits,
+                                            [req.temperature]))[0])
+        self._slots[i].out.append(first)
         self._slots[i].remaining -= 1
         self._last_tok = self._last_tok.at[i, 0].set(first)
         self.stats["prefills"] += 1
@@ -852,7 +901,13 @@ class ServeEngine:
                 if self.faults is not None:
                     logits = self.faults.prefill_logits(
                         self.stats["engine_steps"], s.rid, logits)
-                if not bool(self._pull(jnp.isfinite(logits).all())):
+                # the first token and the sentry's flag in one pull; the
+                # engine key moves on only for a prompt that completes
+                key, keys = _next_keys(self._key, 0)
+                first, finite = self._pull((
+                    self._sample(keys, logits, [s.temperature]),
+                    _finite_rows(logits)))
+                if not finite[0]:
                     self.stats["numeric"] += 1
                     self._finish_slot(i, "numeric")
                     return
@@ -864,11 +919,10 @@ class ServeEngine:
                                    [int(b) for b in
                                     self._tables[i, :n_full]])
                 s.prompt = None
-                self._key, k = jax.random.split(self._key)
-                first = sample_token(k, logits[0], s.temperature)
-                s.out.append(int(first))
+                self._key = key
+                s.out.append(int(first[0]))
                 s.remaining -= 1
-                self._last_tok = self._last_tok.at[i, 0].set(first)
+                self._last_tok = self._last_tok.at[i, 0].set(s.out[-1])
                 self.stats["prefills"] += 1
                 self._retire(i)
             return                          # one chunk per step
@@ -915,9 +969,13 @@ class ServeEngine:
     # down to the host work that held it:
     #   engine.admit    fault hooks, table checks, deadlines, admission
     #   engine.prefill  one chunk of the oldest prefilling slot (paged)
-    #   engine.decode   table growth, the decode dispatch and the (B,)
-    #                   isfinite pull, where the host waits on the device
-    #   engine.sample   per-slot sampling, token feedback and retirement
+    #   engine.decode   table growth, the decode dispatch, the tick's key
+    #                   split and one batched sampler dispatch, then ONE
+    #                   pull of the (B,) tokens and isfinite flags, where
+    #                   the host waits on the device
+    #   engine.sample   per-slot bookkeeping (append, position, retire,
+    #                   quarantine) on the host, and one on-device
+    #                   feedback of the tokens into the next tick's input
     # A step in which no slot decodes ends inside engine.decode.  Outside
     # a trace a span costs well under a microsecond.
 
@@ -965,29 +1023,34 @@ class ServeEngine:
                     self.stats["engine_steps"],
                     [s.rid if s.decoding else -1 for s in self._slots],
                     logits)
-            # numeric sentry: one (B,) host pull per tick.  A non-finite
-            # row quarantines ONLY that slot (reason 'numeric', blocks
-            # refunded); the per-slot sampling keys below are split from
-            # the step key by slot INDEX, so the neighbours' token streams
-            # are bitwise unaffected by the quarantine.
-            finite = self._pull(jnp.isfinite(logits).all(axis=-1))
+            # every row sampled in one dispatch, then ONE host pull of the
+            # tokens with the numeric sentry's (B,) flags.  The row keys
+            # are split from the step key by slot INDEX, so a quarantined
+            # row leaves its neighbours' token streams bitwise unchanged.
+            self._key, keys = _next_keys(self._key, self.n_slots)
+            tokens = self._sample(keys, logits,
+                                  [s.temperature if s.decoding else 0.0
+                                   for s in self._slots])
+            host_tokens, finite = self._pull((tokens,
+                                              _finite_rows(logits)))
         with jax.profiler.TraceAnnotation("engine.sample"):
             self.stats["decode_steps"] += 1
-            self._key, k = jax.random.split(self._key)
-            keys = jax.random.split(k, self.n_slots)
+            # a non-finite row quarantines ONLY its slot (reason
+            # 'numeric', blocks refunded) and feeds nothing back
+            fed = np.zeros(self.n_slots, bool)
             for i, s in enumerate(self._slots):
                 if not s.decoding:
                     continue
-                if not bool(finite[i]):
+                if not finite[i]:
                     self.stats["numeric"] += 1
                     self._finish_slot(i, "numeric")
                     continue
-                tok = int(sample_token(keys[i], logits[i], s.temperature))
-                s.out.append(tok)
+                s.out.append(int(host_tokens[i]))
                 s.pos += 1
                 s.remaining -= 1
-                self._last_tok = self._last_tok.at[i, 0].set(tok)
+                fed[i] = True
                 self._retire(i)
+            self._last_tok = _feed_back(self._last_tok, tokens, fed)
 
     def run(self, requests: list[Request], max_steps: int = 10_000
             ) -> dict[int, list[int]]:

@@ -76,6 +76,30 @@ def test_engine_step_is_tiled_by_its_phases(tmp_path):
     assert per[0] == list(ENGINE[:3]) and list(ENGINE) in per
 
 
+def test_decode_tick_pulls_from_the_device_once():
+    """With every slot decoding, a step's one device->host transfer is
+    the decode tick's pull of the tokens and the sentry's flags."""
+    cfg = registry.reduced_config("qwen1.5-0.5b")
+    params = init_lm(jax.random.PRNGKey(0), cfg, jnp.float32)
+    eng = ServeEngine(cfg, params, n_slots=3, max_seq=64, seed=0,
+                      cache_mode="paged", prefill_chunk=16)
+    for rid in range(3):
+        eng.submit(Request(rid=rid, prompt=list(range(2, 9 + rid)),
+                           max_new=8, temperature=float(rid % 2)))
+    while not all(s.decoding for s in eng._slots):
+        eng.step()
+    pulls = []
+    pull = eng._pull
+    eng._pull = lambda x: pulls.append(x) or pull(x)
+    for _ in range(3):
+        before = len(pulls)
+        eng.step()
+        assert len(pulls) == before + 1
+    assert all(len(x) == 2 for x in pulls)      # tokens with the flags
+    assert eng.stats["sample_calls"] == (eng.stats["decode_steps"]
+                                         + eng.stats["prefills"])
+
+
 def test_trainer_step_is_tiled_by_its_phases(tmp_path):
     tcfg = TrainConfig(total_steps=50, checkpoint_every=2,
                        checkpoint_dir=str(tmp_path / "ck"))

@@ -2,6 +2,7 @@
 temperature, slot reuse."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import registry
@@ -307,3 +308,84 @@ def test_zero_token_drain_cost_is_per_queue_not_per_slot():
     # one drain pass reads the head once; the slot loop (4 busy slots)
     # must not re-read it
     assert _CountingInt.reads <= 2, _CountingInt.reads
+
+
+# ---------------- batched sampling ----------------
+
+TEMPS = [0.0, 0.7, 2.0, 0.0, 1.0, 0.3, -1.0, 1.3]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_batched_sampler_is_bitwise_per_row(dtype):
+    """One batched call gives, row by row, exactly what a call on that
+    row alone gives: the argmax at temperature <= 0, else the categorical
+    draw of the row's key over logits / t in the logits' dtype."""
+    from repro.serve.engine import sample_token
+    vocab = 151936
+    logits = (3 * jax.random.normal(jax.random.PRNGKey(1),
+                                    (8, vocab))).astype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(7), 8)
+    got = np.asarray(sample_token(keys, logits, TEMPS))
+    assert got.dtype == np.int32
+    want = [int(jnp.argmax(logits[i])) if t <= 0 else
+            int(jax.random.categorical(keys[i], logits[i] / t))
+            for i, t in enumerate(TEMPS)]
+    assert got.tolist() == want
+    # an all-greedy batch, as every decode tick of greedy traffic is,
+    # runs the same program and takes each row's argmax
+    greedy = np.asarray(sample_token(keys, logits, [0.0] * 8))
+    assert greedy.tolist() == [int(jnp.argmax(row)) for row in logits]
+
+
+def _per_slot_sampler(seed: int, calls: list):
+    """Per-slot sampling as a stand-in for ``sample_token``: its
+    own copy of the engine's key stream (a prompt's first token takes
+    the split key whole, a decode tick splits it by slot index), one
+    eager draw per row at the row's Python-float temperature."""
+    state = {"key": jax.random.PRNGKey(seed)}
+
+    def sample(keys, logits, temperature):
+        calls.append(logits.shape[0])
+        state["key"], k = jax.random.split(state["key"])
+        rows = ([k] if logits.shape[0] == 1
+                else jax.random.split(k, logits.shape[0]))
+        out = []
+        for i, t in enumerate(np.asarray(temperature).tolist()):
+            out.append(int(jnp.argmax(logits[i])) if t <= 0.0 else int(
+                jax.random.categorical(rows[i], logits[i] / t, axis=-1)))
+        return jnp.asarray(out, jnp.int32)
+    return sample
+
+
+@pytest.mark.parametrize("mode", ["paged", "contiguous"])
+def test_engine_samples_each_tick_once_and_matches_per_slot(mode,
+                                                            monkeypatch):
+    """Four slots mixing greedy and temperature-2 requests: the engine
+    calls ``sample_token`` once per decode tick plus once per completed
+    prompt, whatever the slot count, and its token streams equal those
+    of per-slot sampling over the same key stream."""
+    import repro.serve.engine as engine
+    cfg = registry.reduced_config("qwen1.5-0.5b")
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+
+    def run():
+        eng = ServeEngine(cfg, params, n_slots=4, max_seq=64, seed=3,
+                          cache_mode=mode, prefill_chunk=16,
+                          prefill_buckets=(16, 64))
+        reqs = [Request(rid=i, prompt=list(range(2 + i, 12 + 5 * i)),
+                        max_new=5 + i % 3,
+                        temperature=2.0 if i % 2 else 0.0)
+                for i in range(6)]
+        return eng, eng.run(reqs)
+
+    eng, got = run()
+    assert eng.stats["sample_calls"] == (eng.stats["decode_steps"]
+                                         + eng.stats["prefills"])
+    calls: list = []
+    monkeypatch.setattr(engine, "sample_token", _per_slot_sampler(3, calls))
+    ref_eng, want = run()
+    assert got == want
+    assert len(calls) == ref_eng.stats["sample_calls"] == (
+        ref_eng.stats["decode_steps"] + ref_eng.stats["prefills"])
+    assert set(calls) == {1, 4}            # a prompt, or a whole tick
+    assert len(calls) < sum(len(v) for v in want.values())
