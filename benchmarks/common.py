@@ -7,11 +7,8 @@ from typing import Callable
 import jax
 import numpy as np
 
-ROWS: list[tuple[str, float, str]] = []
-
 
 def emit(name: str, us_per_call: float, derived: str = "") -> None:
-    ROWS.append((name, us_per_call, derived))
     print(f"{name},{us_per_call:.2f},{derived}")
 
 

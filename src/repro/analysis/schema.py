@@ -1,9 +1,7 @@
-"""One declarative validator for every machine-readable artifact.
+"""One declarative validator for machine-readable artifacts.
 
-The per-bench ``check_*_schema`` functions in benchmarks/bench_kernels.py
-and the AUDIT.json check used to be (or would have become) N hand-rolled
-assertion walks; this module is the single engine they all share.  A
-schema is data:
+``analysis/audit.py`` validates every AUDIT.json it writes against
+``AUDIT_SPEC``/``AUDIT_RULES`` through this engine.  A schema is data:
 
   type            isinstance check (bool is NOT an int here)
   {k: spec}       dict with at least these keys, each value checked;
@@ -24,8 +22,6 @@ them — a CI failure names all the drifted fields at once.
 from __future__ import annotations
 
 import json
-
-NUM = ("any_of", int, float)
 
 
 def check(obj, spec, path: str = "$") -> list[str]:
@@ -114,156 +110,6 @@ def validate_file(path: str, spec, rules=(), name: str | None = None):
 
 
 # ---------------------------------------------------------------------------
-# bench artifact schemas (shared with benchmarks/bench_kernels.py)
-# ---------------------------------------------------------------------------
-
-FLASH_INT_SPEC = {
-    "backend": str,
-    "us_per_call": {"flash_pallas_int": NUM, "flash_pallas_int3": NUM},
-    "sweeps_rows": [{"sweeps": int, "word_parity_residual": NUM}],
-}
-FLASH_INT_RULES = [
-    ("both sweep counts {1, 3} present",
-     lambda d: {r["sweeps"] for r in d["sweeps_rows"]} == {1, 3}),
-    ("kernel words match the whole-row unit exactly (residual 0)",
-     lambda d: all(float(r["word_parity_residual"]) == 0.0
-                   for r in d["sweeps_rows"])),
-]
-
-DECODE_SPEC = {
-    "backend": str,
-    "cache_lens": [int],
-    "splits": [int],
-    "us_per_token": {"naive": ("keys", NUM),
-                     "flash_decode": ("keys", ("keys", NUM))},
-    "parity_max_abs_vs_naive": ("keys", NUM),
-    "engine": {"tokens_per_s": {"naive": NUM, "flash_decode": NUM}},
-}
-DECODE_RULES = [
-    ("at least one cache length swept", lambda d: len(d["cache_lens"]) > 0),
-    ("at least one split count swept", lambda d: len(d["splits"]) > 0),
-    ("naive timed at every cache length",
-     lambda d: all(str(t) in d["us_per_token"]["naive"]
-                   for t in d["cache_lens"])),
-    ("flash_decode timed at every (cache length, split)",
-     lambda d: all(str(n) in d["us_per_token"]["flash_decode"][str(t)]
-                   for t in d["cache_lens"] for n in d["splits"])),
-    ("split-KV decode matches naive to 1e-5 at every length",
-     lambda d: all(float(d["parity_max_abs_vs_naive"][str(t)]) <= 1e-5
-                   for t in d["cache_lens"])),
-    ("both engine impls made positive tokens/sec",
-     lambda d: all(v > 0 for v in d["engine"]["tokens_per_s"].values())),
-]
-
-_MODE_SPEC = {"tokens": int, "tokens_per_s": NUM, "cache_copies": int,
-              "concurrent_hwm": int}
-_PRESSURE_MODE_SPEC = {"tokens": int, "tokens_per_s": NUM,
-                       "concurrent_hwm": int, "preemptions": int,
-                       "unterminated": int, "leaked_blocks": int}
-SERVE_SPEC = {
-    "backend": str,
-    "interpret": bool,
-    "equal_hbm_tokens": int,
-    "modes": {"paged": _MODE_SPEC, "contiguous": _MODE_SPEC},
-    "mixed_phase": {"tokens": int, "tokens_per_s": NUM,
-                    "decode_attn_impl": ("eq", "flash_decode"),
-                    "decode_softmax_impl": ("eq", "dualmode"),
-                    "prefill_softmax_impl": ("eq", "float")},
-    "pressure": {"num_blocks": int, "worst_case_demand": int,
-                 "modes": {"worst_case": _PRESSURE_MODE_SPEC,
-                           "reactive": _PRESSURE_MODE_SPEC}},
-}
-SERVE_RULES = [
-    ("both modes produced tokens at positive throughput",
-     lambda d: all(m["tokens"] > 0 and m["tokens_per_s"] > 0
-                   for m in d["modes"].values())),
-    ("paged and contiguous ran the same workload",
-     lambda d: d["modes"]["paged"]["tokens"]
-     == d["modes"]["contiguous"]["tokens"]),
-    ("paged admission never copied a cache",
-     lambda d: d["modes"]["paged"]["cache_copies"] == 0),
-    ("contiguous admission did copy (the cost paged removes)",
-     lambda d: d["modes"]["contiguous"]["cache_copies"] > 0),
-    ("paged out-batches contiguous at equal HBM",
-     lambda d: d["modes"]["paged"]["concurrent_hwm"]
-     > d["modes"]["contiguous"]["concurrent_hwm"]),
-    ("block pool actually used",
-     lambda d: (d["modes"]["paged"].get("blocks_hwm") or 0) > 0),
-    ("prefix sharing found at least one shared block",
-     lambda d: (d["modes"]["paged"].get("shared_blocks") or 0) > 0),
-    ("decode does not stall during chunked prefill",
-     lambda d: (d["modes"]["paged"].get("decode_ticks_per_prefill_step")
-                or 0) >= 1.0),
-    ("mixed-phase engine produced tokens",
-     lambda d: d["mixed_phase"]["tokens"] > 0
-     and d["mixed_phase"]["tokens_per_s"] > 0),
-    ("pressure pool really was under worst-case demand",
-     lambda d: d["pressure"]["num_blocks"]
-     < d["pressure"]["worst_case_demand"]),
-    ("reactive+preempt reaches strictly higher concurrency than "
-     "worst-case reservation at the same pool",
-     lambda d: d["pressure"]["modes"]["reactive"]["concurrent_hwm"]
-     > d["pressure"]["modes"]["worst_case"]["concurrent_hwm"]),
-    ("preemption invisible in output: equal tokens under pressure",
-     lambda d: d["pressure"]["modes"]["reactive"]["tokens"]
-     == d["pressure"]["modes"]["worst_case"]["tokens"]),
-    ("every request terminated under pressure, zero blocks leaked",
-     lambda d: all(m["unterminated"] == 0 and m["leaked_blocks"] == 0
-                   for m in d["pressure"]["modes"].values())),
-    ("pressure actually bit: reactive preempted or blocked admission",
-     lambda d: (d["pressure"]["modes"]["reactive"]["preemptions"]
-                + d["pressure"]["modes"]["reactive"].get("admit_blocked",
-                                                         0)) > 0),
-]
-
-_SEAM_SPEC = {"dense_hbm_bytes": int, "fused_hbm_bytes": int,
-              "saved_bytes": int, "us_dense": NUM, "us_fused": NUM,
-              "parity_max_abs": NUM}
-BLOCK_SPEC = {
-    "backend": str,
-    "interpret": bool,
-    "shape": {"m": int, "d": int, "f": int},
-    "norm_kind": ("in", {"rms", "layer"}),
-    "seams": {"attn_qkv_prologue": _SEAM_SPEC,
-              "attn_out_epilogue": _SEAM_SPEC,
-              "ffn_glu_prologue": _SEAM_SPEC},
-    "block_total": {"dense_hbm_bytes": int, "fused_hbm_bytes": int,
-                    "saved_bytes": int, "saved_frac": NUM},
-}
-# parity bars: the residual-add epilogue is pure elementwise after the
-# norm, so it holds the pinned 1e-5 dense-contract bar; the matmul
-# prologues reassociate the contraction inside the kernel, so their bar
-# is the small-ULP 5e-5 (same reasoning as the fused-FFN parity bar)
-BLOCK_RULES = [
-    ("every fused seam saves HBM traffic (saved_bytes > 0)",
-     lambda d: all(s["saved_bytes"] > 0 for s in d["seams"].values())),
-    ("saved_bytes = dense - fused per seam",
-     lambda d: all(s["saved_bytes"]
-                   == s["dense_hbm_bytes"] - s["fused_hbm_bytes"]
-                   for s in d["seams"].values())),
-    ("residual+norm epilogue holds the pinned dense contract (<= 1e-5)",
-     lambda d: float(d["seams"]["attn_out_epilogue"]["parity_max_abs"])
-     <= 1e-5),
-    ("matmul prologues within small-ULP reassociation (<= 5e-5)",
-     lambda d: all(float(d["seams"][s]["parity_max_abs"]) <= 5e-5
-                   for s in ("attn_qkv_prologue", "ffn_glu_prologue"))),
-    ("block totals are the sum of the seam rows",
-     lambda d: d["block_total"]["saved_bytes"]
-     == sum(s["saved_bytes"] for s in d["seams"].values())
-     and d["block_total"]["dense_hbm_bytes"]
-     == sum(s["dense_hbm_bytes"] for s in d["seams"].values())),
-    ("saved fraction consistent and positive",
-     lambda d: 0.0 < float(d["block_total"]["saved_frac"]) < 1.0),
-]
-
-
-def check_block_json(path: str) -> dict:
-    """Validate BENCH_block.json (the per-seam HBM-traffic artifact the
-    block bench writes) through the shared engine."""
-    return validate_file(path, BLOCK_SPEC, BLOCK_RULES, "BENCH_block.json")
-
-
-# ---------------------------------------------------------------------------
 # AUDIT.json (the auditor's own artifact goes through the same engine)
 # ---------------------------------------------------------------------------
 
@@ -307,8 +153,3 @@ AUDIT_RULES = [
      or a["passes"]["dispatch_table"]["cells"] >= 100),
 ]
 
-
-def check_audit_json(path: str) -> dict:
-    """Validate AUDIT.json through the shared engine (bench smokes call
-    this with ``--check-audit``)."""
-    return validate_file(path, AUDIT_SPEC, AUDIT_RULES, "AUDIT.json")

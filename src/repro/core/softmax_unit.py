@@ -165,9 +165,8 @@ def silu_int(z_fx):
 # three KV sweeps — max, sum, emit — each an online fold whose carry
 # (m, then l) never leaves the int domain, and ANY blocking schedule
 # telescopes to the exact whole-row :func:`softmax_int` words.  These
-# three steps are jnp-traceable and shared verbatim by the three-sweep
-# Pallas kernel body (``flash_pallas_int3``) and the pure-jnp blocked
-# oracle below.  The SNAPPED-max mode further down removes the
+# three steps are jnp-traceable; the pure-jnp blocked oracle below runs
+# them.  The SNAPPED-max mode further down removes the
 # three-sweep restriction: snapping the max to a power of two makes the
 # rescale an exact bit-shift, yielding a true one-sweep word monoid.
 

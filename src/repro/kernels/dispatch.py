@@ -5,8 +5,7 @@ consumers, so model code never switches on strings itself:
 
   softmax    'float' | 'dualmode' | 'dualmode_snap'   (attention probs)
   attention  'auto' | 'naive' | 'flash' | 'flash_pallas'
-             | 'flash_pallas_int' | 'flash_pallas_int3'
-             | 'flash_ring' | 'flash_decode'
+             | 'flash_pallas_int' | 'flash_ring' | 'flash_decode'
   activation 'gelu_exact' | ... (delegates to repro.core.activations)
   ffn        'auto' | 'dense' | 'fused_pallas'  (gated-MLP execution)
 
@@ -14,8 +13,8 @@ Providers register themselves at import time (``models/attention.py``
 registers 'naive', ``models/flash.py`` registers 'flash' and the 'auto'
 rule, ``kernels/flash_attention.py`` registers 'flash_pallas',
 ``kernels/flash_attention_int.py`` registers 'flash_pallas_int' (the
-one-sweep snapped-max unit) and 'flash_pallas_int3' (the three-sweep
-pinned oracle), ``kernels/ring_attention.py`` registers 'flash_ring',
+one-sweep snapped-max unit), ``kernels/ring_attention.py`` registers
+'flash_ring', ``kernels/flash_decode.py`` registers 'flash_decode',
 ``kernels/fused_ffn.py`` registers 'fused_pallas') — the registry itself
 imports nothing from ``models``, which keeps the layering acyclic:
 datapath -> kernels -> dispatch -> models.
@@ -42,7 +41,6 @@ contract is never silently dropped.
 | flash_decode | ok | ok | ok | no | s_q=1 only |
 | flash_pallas | ok | raise | raise | yes | - |
 | flash_pallas_int | raise | ok | ok | no | - |
-| flash_pallas_int3 | raise | ok | raise | no | - |
 | flash_ring | ok | ok | ok | yes | needs mesh, mesh-safe |
 | naive | ok | ok | ok | yes | mesh-safe |
 
@@ -114,8 +112,7 @@ def get_softmax(impl: str) -> Callable:
 
     'float'         : jax.nn.softmax (fp32 accumulate)
     'dualmode'      : the paper's unit, bit-accurate int path (jnp
-                      emulation — same numerics the three-sweep Pallas
-                      kernel executes)
+                      emulation, whole-row classic words)
     'dualmode_snap' : the snapped-max variant of the unit — the
                       whole-row oracle of every STREAMED dual-mode path
                       (one-sweep int flash, dual-mode decode/ring)
@@ -314,9 +311,9 @@ def resolve_attention(impl: str, s_q: int, t_kv: int,
       * any explicit impl + a softmax mode outside its declared
         ``modes``: ValueError — e.g. 'flash'/'flash_pallas' (float
         log-domain by construction) with 'dualmode', or
-        'flash_pallas_int'/'flash_pallas_int3' (the kernels ARE the
-        unit) with 'float'.  Silently dropping a word contract is
-        exactly the bug this guard exists to prevent.
+        'flash_pallas_int' (the kernel IS the unit) with 'float'.
+        Silently dropping a word contract is exactly the bug this guard
+        exists to prevent.
 
     Mesh-aware (opt-in): with a non-empty ``ring_axis``, an 'auto' pick
     of a blocked path — float OR int — upgrades to 'flash_ring' when the

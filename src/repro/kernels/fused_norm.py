@@ -188,7 +188,8 @@ def fused_norm_linear(x, g, b, w, *, kind: str, eps: float,
 
     ``x`` (..., d), ``w`` (d, F) -> (..., F).  ``b=None`` for rms.
     The x tile is read once and both the moments and the matmul consume
-    it in VMEM — the prologue's HBM saving (see BENCH_block.json).
+    it in VMEM — the prologue's HBM saving: the normalized stream is
+    never written to HBM and read back.
     """
     shape = x.shape
     d = shape[-1]

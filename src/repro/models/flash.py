@@ -15,9 +15,8 @@ online_softmax_update`` — the unit's own arithmetic, streamed, and the
 SAME function the Pallas kernel body executes (kernels/flash_attention.py
 is this loop with a Pallas grid around it).  (This module is the FLOAT
 form; the bit-accurate int unit streams through the snapped one-sweep
-kernel in kernels/flash_attention_int.py, with the three-sweep
-'flash_pallas_int3' kept as its oracle — dispatch never pairs 'dualmode'
-with this float path.)
+kernel in kernels/flash_attention_int.py — dispatch never pairs
+'dualmode' with this float path.)
 
 Shapes: q (B,S,K,G,h), k (B,T,K,h), v (B,T,K,hv) -> out (B,S,K,G,hv).
 hv may differ from h (MLA).  Masking: kv position t attends iff
@@ -189,12 +188,12 @@ def use_flash(s_q: int, t: int, threshold: int = 1 << 22) -> bool:
 def blocked_impl(backend: str | None = None) -> str:
     """The 'auto' rule's blocked pick, backend-aware.
 
-    On TPU the compiled Pallas kernel is the fast path; on CPU/interpret
-    backends the Pallas kernel runs the interpreter and loses badly to
-    the pure-JAX blocked graph (BENCH_flash.json: 207ms interpret-mode
-    Pallas vs 81ms flash_jax at the same shape), so 'auto' prefers
-    'flash' there.  Explicit impl strings are never rewritten — this
-    only shapes the 'auto' resolution.
+    On TPU the compiled Pallas kernel is the fast path.  Other backends
+    have no Pallas compiler: the kernel would run the Pallas interpreter,
+    one grid step at a time in Python, while the pure-JAX blocked graph
+    compiles through XLA, so 'auto' prefers 'flash' there.  Explicit
+    impl strings are never rewritten — this only shapes the 'auto'
+    resolution.
     """
     backend = backend or jax.default_backend()
     return "flash_pallas" if backend == "tpu" else "flash"

@@ -5,14 +5,15 @@ it is missing — the tier-1 CI image ships only jax + pytest — property
 tests fall back to a small deterministic sweep over each strategy's
 boundary/representative values instead of failing at collection time.
 The fallback intentionally mirrors only the four strategies this suite
-uses (integers, floats, booleans, sampled_from).
+uses (integers, floats, booleans, sampled_from), and runs every
+``@example`` (placed below ``@given``) before the sweep.
 """
 from __future__ import annotations
 
 import itertools
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                                       # pragma: no cover
     HAVE_HYPOTHESIS = False
@@ -43,9 +44,17 @@ except ImportError:                                       # pragma: no cover
     def settings(**_kwargs):
         return lambda f: f
 
+    def example(*args):
+        def deco(f):
+            f._examples = [args] + getattr(f, "_examples", [])
+            return f
+        return deco
+
     def given(*strategies):
         def deco(f):
             def wrapper():
+                for args in getattr(f, "_examples", []):
+                    f(*args)
                 pools = [s.samples for s in strategies]
                 for combo in itertools.islice(
                         itertools.product(*pools), 32):
@@ -56,4 +65,4 @@ except ImportError:                                       # pragma: no cover
         return deco
 
 
-__all__ = ["HAVE_HYPOTHESIS", "given", "settings", "st"]
+__all__ = ["HAVE_HYPOTHESIS", "example", "given", "settings", "st"]

@@ -182,20 +182,14 @@ DUALMODE_CASES = [c for c in sorted(CASES) if "dtype" not in CASES[c]]
 
 @pytest.mark.parametrize("case", DUALMODE_CASES)
 def test_dualmode_words_int_kernels_vs_naive(case):
-    """Where dualmode applies (f32 operands): the three-sweep oracle
-    carries the whole-row CLASSIC unit's words, the one-sweep snapped
-    kernel the whole-row SNAPPED unit's words; each residual vs its own
-    naive reference is pure numerator@v reduction-order noise, and the
-    two units agree within the max-quantization bound."""
+    """Where dualmode applies (f32 operands): the one-sweep snapped
+    kernel carries the whole-row SNAPPED unit's words — its residual vs
+    naive 'dualmode_snap' is pure numerator@v reduction-order noise —
+    and agrees with the CLASSIC unit within the max-quantization bound."""
     q, k, v, q_pos, kv_valid, causal, _ = _case(case)
     naive = dispatch.get_attention("naive")(
         q, k, v, q_pos=q_pos, kv_valid=kv_valid, causal=causal,
         scale=None, softmax_impl="dualmode")
-    got3 = dispatch.get_attention("flash_pallas_int3")(
-        q, k, v, q_pos=q_pos, kv_valid=kv_valid, causal=causal,
-        scale=None, softmax_impl="dualmode")
-    np.testing.assert_allclose(np.asarray(got3), np.asarray(naive),
-                               atol=1e-5)
     naive_snap = dispatch.get_attention("naive")(
         q, k, v, q_pos=q_pos, kv_valid=kv_valid, causal=causal,
         scale=None, softmax_impl="dualmode_snap")

@@ -85,9 +85,9 @@ def test_use_flash_threshold():
 
 def test_auto_blocked_pick_is_backend_aware(monkeypatch):
     """'auto' streams through the compiled Pallas kernel on TPU and the
-    pure-JAX blocked path on interpret backends (BENCH_flash.json:
-    interpret-mode Pallas ~2.5x slower than flash_jax at the same
-    shape).  Explicit impl strings are never rewritten."""
+    pure-JAX blocked path on interpret backends, where Pallas has no
+    compiler and would run its interpreter.  Explicit impl strings are
+    never rewritten."""
     from repro.kernels import dispatch
     from repro.models.flash import blocked_impl
     assert blocked_impl("tpu") == "flash_pallas"

@@ -187,6 +187,100 @@ def test_paged_matches_contiguous_mixed_workload():
     assert paged.active == 0 and contig.active == 0
 
 
+class _DecodeLogitsTap:
+    """A fault injector that injects nothing and keeps each decode tick's
+    logits."""
+
+    def __init__(self):
+        self.logits = []
+
+    def alloc_shortfall(self, where, step):
+        return False
+
+    def decode_logits(self, step, rids, logits):
+        self.logits.append(np.asarray(logits, np.float32))
+        return logits
+
+    def prefill_logits(self, step, rid, logits):
+        return logits
+
+    def corrupt_tables(self, step, tables, slots):
+        return None
+
+
+@pytest.mark.parametrize("mode", ["paged", "contiguous"])
+def test_mixed_phase_decode_matches_naive_snap(mode):
+    """Float prefill with dual-mode decode through the split-KV kernel: a
+    generated token's attention runs the snapped int split path, so the
+    greedy tokens equal the same engine decoding on the naive whole-row
+    snapped unit, and so do the decode logits (float decode sits about
+    3e-4 away from them, the classic unit about 1e-3)."""
+    cfg = registry.reduced_config("qwen1.5-0.5b")
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+
+    def mk_reqs():
+        return [Request(rid=0, prompt=list(range(5, 25)), max_new=6),
+                Request(rid=1, prompt=[3, 1, 4, 1, 5, 9, 2, 6], max_new=8),
+                Request(rid=2, prompt=list(range(40, 77)), max_new=5)]
+
+    kw = dict(n_slots=2, max_seq=128, seed=0, cache_mode=mode,
+              prefill_softmax_impl="float", decode_softmax_impl="dualmode")
+    if mode == "paged":
+        kw["prefill_chunk"] = 16
+    else:
+        kw["prefill_buckets"] = (16, 64)
+    tap, ref_tap = _DecodeLogitsTap(), _DecodeLogitsTap()
+    eng = ServeEngine(cfg, params, decode_attn_impl="flash_decode",
+                      faults=tap, **kw)
+    assert (eng.prefill_softmax_impl, eng.decode_softmax_impl,
+            eng.decode_attn_impl) == ("float", "dualmode", "flash_decode")
+    ref = ServeEngine(cfg, params, decode_attn_impl="naive", faults=ref_tap,
+                      **dict(kw, decode_softmax_impl="dualmode_snap"))
+    assert ref.decode_attn_impl == "naive"
+    out = eng.run(mk_reqs())
+    assert out == ref.run(mk_reqs())
+    assert all(len(out[r]) > 0 for r in out)
+    assert len(tap.logits) == len(ref_tap.logits) > 0
+    np.testing.assert_allclose(np.stack(tap.logits),
+                               np.stack(ref_tap.logits), atol=5e-5)
+
+
+def test_paged_outbatches_contiguous_at_equal_hbm():
+    """The paged pool holds exactly the contiguous layout's n_slots x
+    max_seq token rows, yet runs twice the slots: admission reserves only
+    what a request can reach, so more requests decode at once, with the
+    same tokens."""
+    cfg = registry.reduced_config("qwen1.5-0.5b")
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    n_slots, max_seq = 2, 64
+
+    def mk_reqs():
+        return [Request(rid=i, prompt=[7 * i + j + 1 for j in range(4 + i)],
+                        max_new=6) for i in range(6)]
+
+    def run_peak(eng):
+        for r in mk_reqs():
+            eng.submit(r)
+        peak = 0
+        while eng.pending():
+            eng.step()
+            peak = max(peak, eng.active)
+        return dict(eng.finished), peak
+
+    contig = ServeEngine(cfg, params, n_slots=n_slots, max_seq=max_seq,
+                         seed=0, cache_mode="contiguous",
+                         prefill_buckets=(16, max_seq))
+    paged = ServeEngine(cfg, params, n_slots=2 * n_slots, max_seq=max_seq,
+                        seed=0, cache_mode="paged", prefill_chunk=16,
+                        num_blocks=n_slots * (max_seq // 8) + 1)
+    assert paged.block_size == 8          # the pool = contiguous's rows
+    out_c, peak_c = run_peak(contig)
+    out_p, peak_p = run_peak(paged)
+    assert out_p == out_c
+    assert peak_p > peak_c
+    assert paged.pool.in_use() == 0
+
+
 def test_paged_eos_retire_matches_contiguous():
     cfg = registry.reduced_config("yi-6b")
     params = init_lm(jax.random.PRNGKey(0), cfg)
